@@ -48,6 +48,7 @@ from repro.graphs.synth import make_features, make_features_mmap, powerlaw_graph
 from repro.models.gnn import init_gnn_params
 from repro.session import AtlasSession
 from repro.storage.layout import GraphStore
+from repro.util.compile_cache import enable_compile_cache
 
 
 class RamColdStore:
@@ -374,7 +375,7 @@ def report_io(sweep) -> None:
 
 
 def run_dist_check(v, d, kind, shards, workers, chunk_vertices, seed,
-                   trace_path=None):
+                   backend="numpy", trace_path=None):
     """``--mode dist``: the shard-parallel engine vs the single-machine
     session on an exact-arithmetic graph (``repro.exact``) — every fp32
     sum exactly representable, so the N-shard run with cross-shard
@@ -391,24 +392,29 @@ def run_dist_check(v, d, kind, shards, workers, chunk_vertices, seed,
     hot_slots = max(16, v // 8)
     cfg = AtlasConfig(
         chunk_bytes=chunk_vertices * d * 4, hot_slots=hot_slots,
-        trace=trace_path is not None,
+        backend=backend, trace=trace_path is not None,
     )
     with tempfile.TemporaryDirectory() as td:
         store = GraphStore.create(td + "/store", csr, feats, num_partitions=4)
-        t0 = time.perf_counter()
-        with AtlasSession(
-            store,
-            config=AtlasConfig(chunk_bytes=cfg.chunk_bytes, hot_slots=hot_slots),
-            workdir=td + "/single",
-        ) as single:
-            ref = single.infer(specs)
-            dense_ref = spills_to_dense(ref.final.spills, v, ref.final.dim)
-        single_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
+        # opened first: its constructor refuses process workers under a
+        # JAX backend before anything runs
         with DistSession(
             store, shards=shards, config=cfg, workers=workers,
             workdir=td + "/dist", trace=trace_path is not None,
         ) as dist:
+            t0 = time.perf_counter()
+            with AtlasSession(
+                store,
+                config=AtlasConfig(
+                    chunk_bytes=cfg.chunk_bytes, hot_slots=hot_slots,
+                    backend=backend,
+                ),
+                workdir=td + "/single",
+            ) as single:
+                ref = single.infer(specs)
+                dense_ref = spills_to_dense(ref.final.spills, v, ref.final.dim)
+            single_s = time.perf_counter() - t0
+            t0 = time.perf_counter()
             result = dist.infer(specs)
             dense_dist = spills_to_dense(result.final.spills, v, result.final.dim)
             version = dist.publish(result.final)
@@ -489,8 +495,9 @@ def main():
                     help="comma list of GNN kinds for --mode dist")
     ap.add_argument("--backend", default="numpy",
                     choices=["numpy", "jax", "pallas", "pallas-interpret"],
-                    help="chunk-aggregation backend for --mode engine and "
-                         "the non-numpy leg of --mode backend")
+                    help="chunk-aggregation backend for --mode engine, "
+                         "--mode dist (thread workers only) and the "
+                         "non-numpy leg of --mode backend")
     ap.add_argument("--pipeline", default="auto",
                     choices=["auto", "staged", "serial"],
                     help="aggregation pipeline for --mode engine runs "
@@ -511,6 +518,7 @@ def main():
                          "Perfetto timeline (Chrome trace-event JSON) to "
                          "PATH; inspect with repro.launch.obs_report")
     args = ap.parse_args()
+    enable_compile_cache()
 
     hot_slots = max(16, int(args.vertices * args.hot_frac))
     print(
@@ -654,6 +662,7 @@ def main():
             dist_rows.append(run_dist_check(
                 args.vertices, args.dim, kind, args.shards,
                 args.dist_workers, args.chunk_vertices, args.seed,
+                backend=args.backend,
                 trace_path=args.trace if i == 0 else None,
             ))
         print("  spills + served rows: bit-identical across all kinds")

@@ -72,8 +72,8 @@ class AtlasConfig:
     graduation_rows: int = 8192
     queue_depth: int = 20
     backend: str = "numpy"  # chunk aggregation: 'numpy' | 'jax' |
-    # 'pallas' (edge_block_spmm kernel; interpret mode off-TPU) |
-    # 'pallas-interpret' (force interpret even on TPU)
+    # 'pallas' (edge_block_spmm kernel compiled for the TPU; raises
+    # off-TPU) | 'pallas-interpret' (the kernel in interpret mode)
     pipeline: str = "auto"  # chunk staging: 'auto' (staged for device
     # backends when threaded) | 'staged' (ring, aggregate overlaps
     # delivery) | 'serial' (aggregate inline on the delivery thread)

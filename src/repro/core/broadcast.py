@@ -10,10 +10,10 @@ Three interchangeable backends, selected by ``AtlasConfig.backend``:
              default on this CPU-only container),
   * jax    — gather/scale/``segment_sum`` jit; the semantics twin of the
              Pallas kernel and the reference it is atol-tested against,
-  * pallas — the ``edge_block_spmm`` one-hot MXU kernel (kernels/), the
-             deployment hot path on TPU.  On hosts without a TPU it
-             degrades to ``interpret=True`` so the same kernel code runs
-             (slowly) everywhere; ``pallas-interpret`` forces that mode.
+  * pallas — the ``edge_block_spmm`` one-hot MXU kernel (kernels/),
+             compiled for the TPU.  It refuses to run on a host whose
+             device is not a TPU; ``pallas-interpret`` runs the same kernel
+             body in interpret mode on any host (CI, equivalence tests).
 
 All backends share one contract::
 
@@ -120,13 +120,14 @@ class JaxChunkAggregator:
     """``chunk_aggregate_jax`` semantics with h2d transfer attribution.
 
     Same outputs as the bare function (shares ``_segment_messages``); the
-    device_put of the four operands is timed into ``h2d_seconds`` so the
-    pipeline can report how much transfer it hides.
+    device_put of the four operands onto ``device`` is timed into
+    ``h2d_seconds`` so the pipeline can report how much transfer it hides.
     """
 
     backend = "jax"
 
-    def __init__(self) -> None:
+    def __init__(self, device: jax.Device) -> None:
+        self.device = device
         self.h2d_seconds = 0.0
         self.tracer = NULL_TRACER
 
@@ -151,10 +152,11 @@ class JaxChunkAggregator:
         w_p[:m] = weights
         with self.tracer.span("h2d", "h2d"):
             t0 = time.monotonic()
-            feats_d = jax.device_put(np.ascontiguousarray(feats, np.float32))
-            src_d = jax.device_put(src_p)
-            seg_d = jax.device_put(seg_p)
-            w_d = jax.device_put(w_p)
+            feats_d, src_d, seg_d, w_d = (
+                jax.device_put(x, self.device)
+                for x in (np.ascontiguousarray(feats, np.float32),
+                          src_p, seg_p, w_p)
+            )
             jax.block_until_ready((feats_d, src_d, seg_d, w_d))
             self.h2d_seconds += time.monotonic() - t0
         out = _segment_messages(
@@ -174,6 +176,23 @@ def _pow2_tiles(n: int, block: int) -> int:
     return block * (1 << (tiles - 1).bit_length())
 
 
+def padded_dims(
+    blocks: tuple[int, int, int, int], n: int, d: int, m: int, n_seg: int
+) -> tuple[int, int, int, int]:
+    """Padded kernel operand dims ``(ep, vp, dp, jp)`` — edges, source
+    rows, feature width, destination segments — for a chunk of ``n``
+    source rows of width ``d`` with ``m`` edges into ``n_seg`` distinct
+    destinations, under ``blocks = (block_e, block_v, block_dst,
+    block_d)``."""
+    be, bv, bdst, bd = blocks
+    return (
+        _pow2_tiles(m, be),
+        -(-n // bv) * bv,
+        -(-d // bd) * bd,
+        _pow2_tiles(n_seg, bdst),
+    )
+
+
 class PallasChunkAggregator:
     """Pallas ``edge_block_spmm`` as a chunk_aggregate backend.
 
@@ -188,25 +207,34 @@ class PallasChunkAggregator:
     padded shapes are pow-2-bucketed so jit traces a handful of shapes
     per layer rather than one per chunk.
 
-    ``interpret="auto"`` resolves from ``jax.default_backend()`` — the
-    compiled kernel on TPU, interpret mode elsewhere (CI still exercises
-    the real kernel body).  Block sizes default to ``auto_blocks`` from
-    the first non-empty chunk's shape and stay frozen for scratch
-    stability; explicit ``block_*`` kwargs override.
+    Operands go to ``device``.  The compiled kernel (``interpret=False``)
+    needs that device to be a TPU and raises otherwise; interpret mode
+    runs the kernel body on any device and is only ever asked for by
+    name.  Block sizes default to ``auto_blocks`` from the first non-empty
+    chunk's shape and stay frozen for scratch stability; explicit
+    ``block_*`` kwargs override.
     """
 
     backend = "pallas"
 
     def __init__(
         self,
-        interpret: bool | str = "auto",
+        device: jax.Device,
+        interpret: bool = False,
         block_e: int | None = None,
         block_v: int | None = None,
         block_dst: int | None = None,
         block_d: int | None = None,
     ) -> None:
-        if interpret == "auto":
-            interpret = jax.default_backend() != "tpu"
+        if not interpret and device.platform != "tpu":
+            raise RuntimeError(
+                f"backend='pallas' compiles the edge_block_spmm kernel for a "
+                f"TPU, but JAX found no TPU: the device is "
+                f"{device.platform} ({device.device_kind}). Use "
+                f"backend='pallas-interpret' to run the kernel in interpret "
+                f"mode off-TPU."
+            )
+        self.device = device
         self.interpret = bool(interpret)
         self._blocks = (
             (block_e, block_v, block_dst, block_d)
@@ -265,11 +293,7 @@ class PallasChunkAggregator:
         if self._blocks is None:
             self._blocks = auto_blocks(n, d, m, n_seg, self.interpret)
         be, bv, bdst, bd = self._blocks
-
-        ep = _pow2_tiles(m, be)
-        vp = -(-n // bv) * bv
-        dp = -(-d // bd) * bd
-        jp = _pow2_tiles(n_seg, bdst)
+        ep, vp, dp, jp = padded_dims(self._blocks, n, d, m, n_seg)
 
         src_p, dst_p, w_p = self._edges(
             ep, m, src_local, np.asarray(seg_ids, np.int32), weights
@@ -278,11 +302,9 @@ class PallasChunkAggregator:
 
         with self.tracer.span("h2d", "h2d"):
             t0 = time.monotonic()
-            operands = (
-                jax.device_put(src_p),
-                jax.device_put(dst_p),
-                jax.device_put(w_p),
-                jax.device_put(feats_p),
+            operands = tuple(
+                jax.device_put(x, self.device)
+                for x in (src_p, dst_p, w_p, feats_p)
             )
             jax.block_until_ready(operands)
             self.h2d_seconds += time.monotonic() - t0
@@ -291,29 +313,34 @@ class PallasChunkAggregator:
             *operands,
             block_e=be, block_v=bv, block_dst=bdst, block_d=bd,
             num_dst_padded=jp, interpret=self.interpret,
-            donate=not self.interpret,
         )
+        # slice on the host: a device-side slice would compile one program
+        # per distinct n_seg, i.e. per chunk
         return (
             unique_dst.astype(np.int64),
-            np.asarray(out[:n_seg, :d]),
+            np.asarray(out)[:n_seg, :d],
             counts.astype(np.int64),
         )
 
 
-def chunk_aggregate(backend: str = "numpy"):
+def chunk_aggregate(backend: str = "numpy", device_index: int = 0):
     """Resolve a backend name to a callable with the shared contract.
 
-    ``numpy``/``jax`` are stateless-per-layer; ``pallas`` returns a fresh
-    aggregator object (call once per layer — it carries scratch buffers).
-    ``pallas-interpret`` forces interpret mode even on a TPU host, which
-    is what CI and the equivalence tests use.
+    ``numpy`` is a plain function and touches no JAX device.  The device
+    backends return a fresh aggregator object (call once per layer — it
+    carries scratch buffers) bound to ``jax.devices()[device_index]``,
+    wrapping round when there are fewer devices than indices: shard
+    ``s`` of a sharded run passes ``s``, so each shard's kernels run on
+    its own chip.  ``pallas`` is the compiled kernel and raises off-TPU;
+    ``pallas-interpret`` runs it in interpret mode on any host, which is
+    what CI and the equivalence tests use.
     """
     if backend == "numpy":
         return chunk_aggregate_numpy
+    if backend not in ("jax", "pallas", "pallas-interpret"):
+        raise ValueError(f"unknown broadcast backend {backend!r}")
+    devices = jax.devices()
+    device = devices[device_index % len(devices)]
     if backend == "jax":
-        return JaxChunkAggregator()
-    if backend == "pallas":
-        return PallasChunkAggregator(interpret="auto")
-    if backend == "pallas-interpret":
-        return PallasChunkAggregator(interpret=True)
-    raise ValueError(f"unknown broadcast backend {backend!r}")
+        return JaxChunkAggregator(device)
+    return PallasChunkAggregator(device, interpret=backend == "pallas-interpret")

@@ -274,27 +274,36 @@ class MeshExchange:
 
     def _all_to_all(self, ids, cnt, rows):
         import jax
-        from jax.sharding import Mesh, PartitionSpec as P
-
-        from repro.dist.mesh import shard_map
+        from jax.sharding import Mesh
 
         mesh = Mesh(
             np.array(jax.devices()[: self.num_shards]), ("shards",)
         )
-
-        def route(i, c, r):
-            # local views [1, S, K(, W)] -> squeeze the owner dim, route
-            # the dst dim across the mesh, restore the owner dim
-            i, c, r = i[0], c[0], r[0]
-            a2a = lambda x: jax.lax.all_to_all(  # noqa: E731
-                x, "shards", split_axis=0, concat_axis=0, tiled=True
-            )
-            return a2a(i)[None], a2a(c)[None], a2a(r)[None]
-
-        spec = (P("shards"), P("shards"), P("shards"))
-        fn = jax.jit(shard_map(route, mesh, spec, spec))
-        r_ids, r_cnt, r_rows = fn(ids, cnt, rows)
+        r_ids, r_cnt, r_rows = all_to_all_route(mesh)(ids, cnt, rows)
         return np.asarray(r_ids), np.asarray(r_cnt), np.asarray(r_rows)
+
+
+def all_to_all_route(mesh):
+    """The jitted bucket route over a 1-D ``("shards",)`` mesh: takes the
+    stacked ``[S, S, K]`` ids, ``[S, S, K]`` counts and ``[S, S, K, W]``
+    rows (owner shard first) and returns them with the two shard axes
+    swapped, by one tiled ``all_to_all`` per tensor under ``shard_map``."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from repro.dist.mesh import shard_map
+
+    def route(i, c, r):
+        # local views [1, S, K(, W)] -> squeeze the owner dim, route
+        # the dst dim across the mesh, restore the owner dim
+        i, c, r = i[0], c[0], r[0]
+        a2a = lambda x: jax.lax.all_to_all(  # noqa: E731
+            x, "shards", split_axis=0, concat_axis=0, tiled=True
+        )
+        return a2a(i)[None], a2a(c)[None], a2a(r)[None]
+
+    spec = (P("shards"), P("shards"), P("shards"))
+    return jax.jit(shard_map(route, mesh, spec, spec))
 
 
 def make_exchange(
@@ -313,5 +322,6 @@ __all__ = [
     "ExchangeAborted",
     "LocalExchange",
     "MeshExchange",
+    "all_to_all_route",
     "make_exchange",
 ]

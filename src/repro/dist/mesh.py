@@ -4,7 +4,7 @@ execution model as a push-style SpMM over a (data, model) /
 
 (Salvaged from the seed's ``repro.distributed.atlas_dist``; the
 out-of-core shard harness in ``repro.dist`` reuses the ``shard_map``
-compat wrapper and the (src_shard, dst_shard) pre-bucketing idea, and
+wrapper and the (src_shard, dst_shard) pre-bucketing idea, and
 ``MeshExchange`` routes its buckets with the same tiled ``all_to_all``.)
 
 The paper's single-machine insight — *stream every source feature exactly
@@ -46,18 +46,12 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.graphs.csr import CSRGraph, degrees_from_csr
 
-try:  # JAX >= 0.6 new location
-    from jax import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_vma=check_rep)
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_old
 
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_rep)
+def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
+    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                      check_vma=check_rep)
 
 
 @dataclasses.dataclass

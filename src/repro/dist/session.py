@@ -251,10 +251,11 @@ class DistSession:
     (infer → publish → reader), N shard workers per layer.
 
     ``workers='thread'`` runs shards as threads in this process (required
-    for ``exchange='mesh'``); ``workers='process'`` spawns one
+    for ``exchange='mesh'`` and for JAX backends, where shard ``s`` runs
+    its kernels on ``jax.devices()[s]``); ``workers='process'`` spawns one
     ``repro.launch.infer_dist --worker`` subprocess per shard per layer —
     the CPU-only single-host multi-process harness — and requires
-    ``exchange='local'``.  ``publish``/``reader``/pinning/GC delegate to
+    ``exchange='local'`` and ``backend='numpy'``.  ``publish``/``reader``/pinning/GC delegate to
     an inner ``AtlasSession``, so serving semantics (MVCC versions, pins,
     ``retain``/``retain_ttl``) are identical to single-machine."""
 
@@ -281,6 +282,15 @@ class DistSession:
             raise ValueError(
                 "workers='process' requires exchange='local' (the mesh "
                 "exchange rendezvouses on an in-process barrier)"
+            )
+        if workers == "process" and self.config.backend != "numpy":
+            # every worker process would open the accelerator, and a chip
+            # belongs to one process: the others fail or hang
+            raise ValueError(
+                f"workers='process' runs one JAX process per shard, but a "
+                f"chip belongs to one process; backend="
+                f"{self.config.backend!r} needs workers='thread' (one "
+                f"process drives every chip)"
             )
         self.exchange_kind = exchange
         self.workers_kind = workers

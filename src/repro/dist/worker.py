@@ -206,7 +206,8 @@ def run_shard_layer(
         threaded=cfg.threaded,
         tracer=tr,
     )
-    aggregate = chunk_aggregate(cfg.backend)
+    # device backends: this shard's kernels run on jax.devices()[shard]
+    aggregate = chunk_aggregate(cfg.backend, device_index=shard)
     if hasattr(aggregate, "tracer"):
         aggregate.tracer = tr
 
@@ -369,6 +370,7 @@ def run_shard_layer(
         "max_span": span["max_span"],
         "barrier_seconds": barrier_seconds,
         "bytes_inflight": bytes_inflight,
+        "device": str(getattr(aggregate, "device", "host")),
         "exchange": {
             "sent_bytes": sent_bytes,
             "recv_bytes": recv_bytes,

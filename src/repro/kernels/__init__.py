@@ -2,6 +2,8 @@
 
 Kernels (each: <name>.py kernel + ops.py jit wrapper + ref.py oracle):
   * edge_block_spmm — ATLAS broadcast aggregation as one-hot MXU GEMMs
+                      (driven by core.broadcast.PallasChunkAggregator,
+                      not ops.py)
   * fused_graduate  — graduation transform act(x @ W + b), fused epilogue
   * flash_attention — causal GQA flash attention (LM prefill hot-spot)
   * ssd_chunk       — Mamba-2 state-space-duality chunked scan
@@ -10,7 +12,6 @@ Kernels (each: <name>.py kernel + ops.py jit wrapper + ref.py oracle):
 
 from repro.kernels.ops import (  # noqa: F401
     attention,
-    broadcast_aggregate,
     graduate,
     ssd,
 )

@@ -31,9 +31,6 @@ Entry points:
 * ``edge_block_spmm_padded`` — the jitted pre-aligned fast path used by
   ``core.broadcast.PallasChunkAggregator``, which pads on the host into
   reused scratch buffers and ships them with one ``device_put`` each.
-  On a real device the operand buffers are donated so XLA can reuse
-  them; on CPU (interpret mode) donation is skipped — it would only
-  emit unused-donation warnings.
 """
 
 from __future__ import annotations
@@ -97,8 +94,11 @@ def _spmm_kernel(
     # gather: one-hot over this source tile (rows outside the tile -> 0)
     v_ids = v * vt + jax.lax.broadcasted_iota(jnp.int32, (src.shape[0], vt), 1)
     src_oh = (src[:, None] == v_ids).astype(jnp.float32)
+    # HIGHEST: f32 contraction on the MXU; a bf16 pass would round the
+    # features past the engine's 1e-4 error bound against the reference
     msgs = jnp.dot(
         src_oh, feats_ref[...].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
     msgs = msgs * w[:, None]
@@ -109,7 +109,8 @@ def _spmm_kernel(
     )
     dst_oh = (dst[:, None] == j_ids).astype(jnp.float32)
     out_ref[...] += jnp.dot(
-        dst_oh.T, msgs, preferred_element_type=jnp.float32
+        dst_oh.T, msgs, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
 
 
@@ -148,11 +149,6 @@ def _spmm_call(
 _STATIC = ("block_e", "block_v", "block_dst", "block_d", "num_dst_padded",
            "interpret")
 _spmm_jit = jax.jit(_spmm_call, static_argnames=_STATIC)
-# donated operands let XLA reuse the staged chunk buffers on device;
-# donation on CPU backends only produces unused-donation warnings
-_spmm_jit_donated = jax.jit(
-    _spmm_call, static_argnames=_STATIC, donate_argnums=(0, 1, 2, 3)
-)
 
 
 def edge_block_spmm_padded(
@@ -167,13 +163,11 @@ def edge_block_spmm_padded(
     block_d: int,
     num_dst_padded: int,
     interpret: bool = False,
-    donate: bool = False,
 ) -> jax.Array:
     """Pre-aligned fast path: every operand already a block multiple,
     edge padding carries ``src = dst = -1`` and ``w = 0``.  Returns the
     padded ``[num_dst_padded, dp]`` accumulator (slice it yourself)."""
-    call = _spmm_jit_donated if donate else _spmm_jit
-    return call(
+    return _spmm_jit(
         src_p, dst_p, w_p, feats_p,
         block_e=block_e, block_v=block_v, block_dst=block_dst,
         block_d=block_d, num_dst_padded=num_dst_padded, interpret=interpret,

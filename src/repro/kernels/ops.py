@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.edge_block_spmm import edge_block_spmm
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_graduate import fused_graduate
 from repro.kernels.ssd_chunk import ssd_scan
@@ -22,12 +21,6 @@ from repro.kernels.ssd_chunk import ssd_scan
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
-
-
-@functools.partial(jax.jit, static_argnames=("num_dst",))
-def broadcast_aggregate(feats, src, dst, w, num_dst: int):
-    """ATLAS chunk aggregation (one-hot MXU SpMM). Returns [num_dst, D]."""
-    return edge_block_spmm(feats, src, dst, w, num_dst, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("activation",))
@@ -49,7 +42,6 @@ def ssd(x, a, b, c):
 
 
 # re-exported oracles so tests import one module
-edge_block_spmm_ref = ref.edge_block_spmm_ref
 fused_graduate_ref = ref.fused_graduate_ref
 gqa_attention_ref = ref.gqa_attention_ref
 mha_attention_ref = ref.mha_attention_ref

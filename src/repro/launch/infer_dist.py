@@ -8,6 +8,10 @@ the single-machine ``AtlasSession`` on the same graph::
     PYTHONPATH=src python -m repro.launch.infer_dist \
         --vertices 20000 --shards 2 --workers process --kind sage
 
+With a JAX ``--backend`` (thread workers only: a chip belongs to one
+process), shard ``s`` aggregates on ``jax.devices()[s]`` and the
+single-machine check runs the same backend on one device.
+
 Worker mode (``--worker``): one shard of one layer, spawned per layer by
 the process-mode coordinator.  Streams the shard's source range, routes
 cross-shard buckets through the file-backed ``LocalExchange``, barriers
@@ -38,7 +42,9 @@ def _worker_main(args) -> int:
     from repro.obs.trace import Tracer
     from repro.storage.layout import GraphStore
     from repro.storage.spill import SpillFile, SpillSet
+    from repro.util.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     exchange = LocalExchange(
         args.exchange_root, args.shards, timeout_s=args.exchange_timeout
     )
@@ -95,7 +101,9 @@ def _coordinator_main(args) -> int:
     from repro.exact import exact_graph_and_specs
     from repro.session import AtlasSession
     from repro.storage.layout import GraphStore
+    from repro.util.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     with tempfile.TemporaryDirectory() as td:
         workdir = args.workdir or td
         csr, feats, specs = exact_graph_and_specs(
@@ -106,7 +114,7 @@ def _coordinator_main(args) -> int:
         )
         cfg = AtlasConfig(
             chunk_bytes=args.chunk_bytes, hot_slots=args.hot_slots,
-            trace=args.trace,
+            backend=args.backend, trace=args.trace,
         )
         with DistSession(
             store, shards=args.shards, config=cfg, exchange=args.exchange,
@@ -133,7 +141,8 @@ def _coordinator_main(args) -> int:
         if not args.no_check:
             with AtlasSession(
                 store, config=AtlasConfig(
-                    chunk_bytes=args.chunk_bytes, hot_slots=args.hot_slots
+                    chunk_bytes=args.chunk_bytes, hot_slots=args.hot_slots,
+                    backend=args.backend,
                 ),
                 workdir=os.path.join(workdir, "single"),
             ) as single:
@@ -175,6 +184,9 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--exchange", choices=["local", "mesh"], default="local")
     ap.add_argument("--workers", choices=["thread", "process"], default="process")
+    ap.add_argument("--backend", default="numpy",
+                    choices=["numpy", "jax", "pallas", "pallas-interpret"],
+                    help="chunk aggregation; JAX backends need --workers thread")
     ap.add_argument("--chunk-bytes", type=int, default=1 << 20)
     ap.add_argument("--hot-slots", type=int, default=None)
     ap.add_argument("--workdir", default=None, help="keep run state here")

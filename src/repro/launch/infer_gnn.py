@@ -25,6 +25,7 @@ from repro.graphs.synth import make_features, powerlaw_graph
 from repro.models.gnn import dense_reference, init_gnn_params
 from repro.session import AtlasSession
 from repro.storage.layout import GraphStore
+from repro.util.compile_cache import enable_compile_cache
 
 
 def main():
@@ -46,6 +47,7 @@ def main():
                     help="publish the final layer and sanity-serve lookups")
     ap.add_argument("--workdir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     csr = powerlaw_graph(args.vertices, args.degree, seed=1,
                          self_loops=(args.model == "gcn"))

@@ -254,3 +254,53 @@ def test_mesh_exchange_bit_identity():
         env_extra={"XLA_FLAGS": "--xla_force_host_platform_device_count=2"},
     )
     assert report["bit_identical"] and report["served_identical"]
+
+
+def test_mesh_exchange_places_each_shard_on_its_own_device():
+    """4 shards on 4 host-platform devices with a JAX backend: shard s
+    aggregates on device s, and the result still equals the one-device
+    run of the same backend bitwise."""
+    report = run_cli(
+        [
+            "--vertices", "1000", "--feat-dim", "8", "--kind", "sage",
+            "--shards", "4", "--workers", "thread", "--exchange", "mesh",
+            "--backend", "pallas-interpret",
+            "--chunk-bytes", str(1 << 14), "--hot-slots", "96",
+        ],
+        env_extra={"XLA_FLAGS": "--xla_force_host_platform_device_count=4"},
+    )
+    assert report["bit_identical"] and report["served_identical"]
+    for reports in report["shard_reports"].values():
+        assert len({r["device"] for r in reports}) == 4
+
+
+@pytest.mark.parametrize("backend", ["jax", "pallas", "pallas-interpret"])
+def test_process_workers_refuse_jax_backends_before_spawning(
+    tmp_path, monkeypatch, backend
+):
+    """A chip belongs to one process: process workers under a JAX backend
+    are refused in the constructor, before any worker starts."""
+    store, _ = exact_store(tmp_path, v=200)
+
+    def no_spawn(*a, **kw):
+        raise AssertionError("a worker process was spawned")
+
+    monkeypatch.setattr(subprocess, "Popen", no_spawn)
+    with pytest.raises(ValueError, match="chip belongs to one process"):
+        DistSession(store, shards=2, workers="process",
+                    config=dist_cfg(backend=backend))
+
+
+def test_bench_delivery_dist_refuses_process_workers_with_jax_backend():
+    """``bench_delivery --mode dist --backend pallas`` with process workers
+    fails the same way, before its single-machine leg runs."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "bench_delivery", os.path.join(REPO, "benchmarks", "bench_delivery.py")
+    )
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    with pytest.raises(ValueError, match="chip belongs to one process"):
+        bench.run_dist_check(400, 8, "gcn", 2, "process", 64, seed=7,
+                             backend="pallas")

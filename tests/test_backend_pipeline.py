@@ -117,11 +117,35 @@ def test_chunk_aggregate_dispatcher():
     assert isinstance(chunk_aggregate("jax"), JaxChunkAggregator)
     p = chunk_aggregate("pallas-interpret")
     assert isinstance(p, PallasChunkAggregator) and p.interpret
-    # 'pallas' resolves interpret from the host backend — on this CPU
-    # container it must degrade to interpret mode, not crash
-    assert chunk_aggregate("pallas").interpret is (True)
+    # 'pallas' is the compiled kernel: off-TPU it refuses, pointing at
+    # the interpret backend, instead of quietly interpreting
+    with pytest.raises(RuntimeError, match="no TPU.*pallas-interpret"):
+        chunk_aggregate("pallas")
     with pytest.raises(ValueError, match="unknown broadcast backend"):
         chunk_aggregate("cuda")
+
+
+def test_pallas_backend_session_fails_without_tpu(tmp_path):
+    """The whole entry point fails on a host without a TPU instead of
+    running the kernel in interpret mode."""
+    v, d = 200, 8
+    csr = powerlaw_graph(v, 4, seed=41)
+    store = build_store(tmp_path, csr, make_features(v, d, seed=41))
+    specs = init_gnn_params("sage", [d, 4], seed=5)
+    cfg = AtlasConfig(chunk_bytes=64 * d * 4, backend="pallas")
+    with AtlasSession(store, config=cfg, workdir=str(tmp_path / "w")) as s:
+        with pytest.raises(RuntimeError, match="no TPU"):
+            s.infer(specs)
+
+
+def test_device_aggregators_place_operands_on_their_device():
+    import jax
+
+    devices = jax.devices()
+    for backend in ("jax", "pallas-interpret"):
+        for index in range(3):
+            agg = chunk_aggregate(backend, device_index=index)
+            assert agg.device == devices[index % len(devices)]
 
 
 # --------------------------------------------------------------------------

@@ -544,3 +544,27 @@ def test_frontend_over_session_reader(tmp_path):
             for f in futs:
                 assert np.array_equal(f.result(30), rows[f.ids])
         assert fe.waves >= 1
+
+
+def test_bench_serve_processes_parent_never_starts_a_jax_backend():
+    """``bench_serve --processes`` forks its readers: a parent that had
+    started a JAX backend would hold the chip its children need, so the
+    parent must reach the end of the sweep without ever starting one."""
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import runpy, sys\n"
+        "sys.argv = ['bench_serve.py', '--processes', '2', '--vertices', "
+        "'3000', '--dim', '8', '--batches', '10', '--warm-batches', '2', "
+        "'--raw-files', '2', '--rows-per-file', '2048']\n"
+        "runpy.run_path('benchmarks/bench_serve.py', run_name='__main__')\n"
+        "from jax._src import xla_bridge\n"
+        "print('backend_started', xla_bridge.backends_are_initialized())\n"
+    )
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"))
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, cwd=repo, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.strip().splitlines()[-1] == "backend_started False"
