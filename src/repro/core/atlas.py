@@ -129,6 +129,13 @@ class LayerMetrics:
     aggregate_seconds: float = 0.0  # time inside aggregate() calls
     h2d_seconds: float = 0.0  # host->device staging (jax/pallas backends)
     pipeline_stall_seconds: float = 0.0  # delivery thread waits on the ring
+    # the rest of the aggregate call's round trip (jax/pallas backends)
+    dedup_seconds: float = 0.0  # np.unique, segment ids, operand padding
+    kernel_wait_seconds: float = 0.0  # kernel call + wait for its output
+    d2h_seconds: float = 0.0  # copy of the padded output to the host
+    # the delivery thread's per-chunk work
+    deliver_seconds: float = 0.0  # shield set -> both _deliver -> cleared
+    evict_seconds: float = 0.0  # cold-store eviction and reload
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -246,6 +253,7 @@ class AtlasEngine:
         self.last_tracer = tr
         t0 = time.perf_counter()
         tr.begin(f"layer_{layer_index}", "layer")
+        tr.begin("open_layer", "setup")
         num_vertices = csr.num_vertices
 
         required = in_deg.astype(np.int64).copy()
@@ -291,6 +299,7 @@ class AtlasEngine:
             orchestrator=orch,
             policy=policy,
             cold=cold,
+            tracer=tr,
         )
         # write-back scheduler: spill flushes become enqueue-and-continue;
         # durability collapses into one group-commit barrier at layer end
@@ -371,49 +380,61 @@ class AtlasEngine:
                     cleanup()
                 except BaseException:
                     pass
+            tr.end("open_layer", "setup")
             tr.end(f"layer_{layer_index}", "layer")
             raise
+        tr.end("open_layer", "setup")
         self_coef = self_coefficient(spec)
         agg_col = spec.in_dim if spec.kind == "sage" else 0
 
         reload_fracs: list[float] = []
         chunks = 0
+        deliver_seconds = 0.0
         # reusable eviction shield: one bool per vertex, set/cleared per
         # chunk in O(#destinations) — replaces the per-chunk Python set
         shield = np.zeros(num_vertices, dtype=bool)
         commit_done = pending_commit is None
+        closing = False
         try:
             for chunk, (u_dst, partial, counts) in pipe:
                 chunks += 1
+                with tr.span("deliver", "deliver"):
+                    t_deliver = time.perf_counter()
+                    # shield everything receiving messages in this chunk
+                    shield[u_dst] = True
+                    if spec.extra_self_message:
+                        shield[chunk.start_id : chunk.end_id] = True
 
-                # shield everything receiving messages in this chunk
-                shield[u_dst] = True
-                if spec.extra_self_message:
-                    shield[chunk.start_id : chunk.end_id] = True
-
-                n_reload = 0
-                if spec.extra_self_message:
-                    ids = np.arange(chunk.start_id, chunk.end_id, dtype=np.int64)
-                    self_rows = chunk.feats.astype(np.float32) * np.float32(self_coef)
-                    n_reload += self._deliver(
-                        mm, orch, grad, ids, self_rows,
-                        np.ones(len(ids), dtype=np.int64),
-                        col_offset=0, shield=shield, chunk_index=chunk.index,
+                    n_reload = 0
+                    if spec.extra_self_message:
+                        ids = np.arange(
+                            chunk.start_id, chunk.end_id, dtype=np.int64
+                        )
+                        self_rows = chunk.feats.astype(
+                            np.float32
+                        ) * np.float32(self_coef)
+                        n_reload += self._deliver(
+                            mm, orch, grad, ids, self_rows,
+                            np.ones(len(ids), dtype=np.int64),
+                            col_offset=0, shield=shield,
+                            chunk_index=chunk.index,
+                        )
+                    if len(u_dst):
+                        n_reload += self._deliver(
+                            mm, orch, grad, u_dst, partial, counts,
+                            col_offset=agg_col, shield=shield,
+                            chunk_index=chunk.index,
+                        )
+                    denom = len(u_dst) + (
+                        chunk.num_vertices if spec.extra_self_message else 0
                     )
-                if len(u_dst):
-                    n_reload += self._deliver(
-                        mm, orch, grad, u_dst, partial, counts,
-                        col_offset=agg_col, shield=shield, chunk_index=chunk.index,
-                    )
-                denom = len(u_dst) + (
-                    chunk.num_vertices if spec.extra_self_message else 0
-                )
-                if denom:
-                    reload_fracs.append(n_reload / denom)
+                    if denom:
+                        reload_fracs.append(n_reload / denom)
 
-                shield[u_dst] = False
-                if spec.extra_self_message:
-                    shield[chunk.start_id : chunk.end_id] = False
+                    shield[u_dst] = False
+                    if spec.extra_self_message:
+                        shield[chunk.start_id : chunk.end_id] = False
+                    deliver_seconds += time.perf_counter() - t_deliver
 
                 if not commit_done:
                     # overlap point: the previous layer's barrier has been
@@ -421,11 +442,15 @@ class AtlasEngine:
                     # first chunk was read, staged, and delivered — join
                     # it and let the caller advance the manifest now
                     commit_done = True
-                    pending_commit()
+                    with tr.span("layer_commit", "session"):
+                        pending_commit()
 
+            tr.begin("close_layer", "teardown")
+            closing = True
             if not commit_done:
                 commit_done = True
-                pending_commit()
+                with tr.span("layer_commit", "session"):
+                    pending_commit()
 
             try:
                 grad.close()
@@ -488,6 +513,8 @@ class AtlasEngine:
                     cleanup()
                 except BaseException:
                     pass
+            if closing:
+                tr.end("close_layer", "teardown")
             tr.end(f"layer_{layer_index}", "layer")
             raise
         finally:
@@ -496,6 +523,7 @@ class AtlasEngine:
 
         cold.close()
 
+        tr.end("close_layer", "teardown")
         tr.end(f"layer_{layer_index}", "layer")
         span = orch.span_stats()
         tail_seconds = grad.tail_seconds + writer.tail_seconds
@@ -529,6 +557,11 @@ class AtlasEngine:
             # join (see StagedAggregation.h2d_seconds)
             h2d_seconds=pipe.h2d_seconds,
             pipeline_stall_seconds=pipe.stall_seconds,
+            dedup_seconds=pipe.dedup_seconds,
+            kernel_wait_seconds=pipe.kernel_wait_seconds,
+            d2h_seconds=pipe.d2h_seconds,
+            deliver_seconds=deliver_seconds,
+            evict_seconds=mm.evict_seconds,
         )
         if not own_scheduler:
             if barrier_handle is not None:

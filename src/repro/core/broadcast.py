@@ -22,7 +22,8 @@ All backends share one contract::
 with ``unique_dst`` sorted ascending — callers (``_deliver``) rely on one
 row per distinct destination.  The jax/pallas backends are *objects* (not
 bare functions) so they can carry reusable host scratch between chunks
-and account ``h2d_seconds`` separately from kernel time.
+and time each step of the round trip (``dedup_seconds``, ``h2d_seconds``,
+``kernel_wait_seconds``, ``d2h_seconds``) apart.
 """
 
 from __future__ import annotations
@@ -117,18 +118,25 @@ def chunk_aggregate_jax(
 
 
 class JaxChunkAggregator:
-    """``chunk_aggregate_jax`` semantics with h2d transfer attribution.
+    """``chunk_aggregate_jax`` semantics with the round trip timed.
 
-    Same outputs as the bare function (shares ``_segment_messages``); the
-    device_put of the four operands onto ``device`` is timed into
-    ``h2d_seconds`` so the pipeline can report how much transfer it hides.
+    Same outputs as the bare function (shares ``_segment_messages``).
+    Each step of the per-chunk round trip is timed into its own counter
+    and traced under its own span: the host dictionary and padding
+    (``dedup_seconds``), the device_put of the four operands onto
+    ``device`` (``h2d_seconds``), the kernel call and the wait for it
+    (``kernel_wait_seconds``) and the copy of the output back to the host
+    (``d2h_seconds``).
     """
 
     backend = "jax"
 
     def __init__(self, device: jax.Device) -> None:
         self.device = device
+        self.dedup_seconds = 0.0
         self.h2d_seconds = 0.0
+        self.kernel_wait_seconds = 0.0
+        self.d2h_seconds = 0.0
         self.tracer = NULL_TRACER
 
     def __call__(self, feats, src_local, dst, weights):
@@ -138,33 +146,45 @@ class JaxChunkAggregator:
                 np.empty((0, feats.shape[1]), dtype=np.float32),
                 np.empty(0, dtype=np.int64),
             )
-        unique_dst, seg_ids, counts = np.unique(
-            dst, return_inverse=True, return_counts=True
-        )
-        m = len(dst)
-        pad = 1 << (m - 1).bit_length()
-        n_seg = len(unique_dst)
-        src_p = np.zeros(pad, dtype=np.int32)
-        src_p[:m] = src_local
-        seg_p = np.full(pad, n_seg, dtype=np.int32)
-        seg_p[:m] = seg_ids
-        w_p = np.zeros(pad, dtype=np.float32)
-        w_p[:m] = weights
-        with self.tracer.span("h2d", "h2d"):
-            t0 = time.monotonic()
+        tr = self.tracer
+        with tr.span("dedup", "dedup"):
+            t0 = time.perf_counter()
+            unique_dst, seg_ids, counts = np.unique(
+                dst, return_inverse=True, return_counts=True
+            )
+            m = len(dst)
+            pad = 1 << (m - 1).bit_length()
+            n_seg = len(unique_dst)
+            src_p = np.zeros(pad, dtype=np.int32)
+            src_p[:m] = src_local
+            seg_p = np.full(pad, n_seg, dtype=np.int32)
+            seg_p[:m] = seg_ids
+            w_p = np.zeros(pad, dtype=np.float32)
+            w_p[:m] = weights
+            self.dedup_seconds += time.perf_counter() - t0
+        with tr.span("h2d", "h2d"):
+            t0 = time.perf_counter()
             feats_d, src_d, seg_d, w_d = (
                 jax.device_put(x, self.device)
                 for x in (np.ascontiguousarray(feats, np.float32),
                           src_p, seg_p, w_p)
             )
             jax.block_until_ready((feats_d, src_d, seg_d, w_d))
-            self.h2d_seconds += time.monotonic() - t0
-        out = _segment_messages(
-            feats_d, src_d, seg_d, w_d, num_segments=n_seg + 1
-        )
+            self.h2d_seconds += time.perf_counter() - t0
+        with tr.span("kernel_wait", "kernel"):
+            t0 = time.perf_counter()
+            out = _segment_messages(
+                feats_d, src_d, seg_d, w_d, num_segments=n_seg + 1
+            )
+            out.block_until_ready()
+            self.kernel_wait_seconds += time.perf_counter() - t0
+        with tr.span("d2h", "d2h"):
+            t0 = time.perf_counter()
+            host = np.asarray(out)
+            self.d2h_seconds += time.perf_counter() - t0
         return (
             unique_dst.astype(np.int64),
-            np.asarray(out[:n_seg]),
+            host[:n_seg],
             counts.astype(np.int64),
         )
 
@@ -241,7 +261,10 @@ class PallasChunkAggregator:
             if all((block_e, block_v, block_dst, block_d))
             else None
         )
+        self.dedup_seconds = 0.0
         self.h2d_seconds = 0.0
+        self.kernel_wait_seconds = 0.0
+        self.d2h_seconds = 0.0
         self.tracer = NULL_TRACER
         self._feat_scratch: dict[tuple[int, int], np.ndarray] = {}
         self._edge_scratch: dict[int, tuple[np.ndarray, np.ndarray, np.ndarray]] = {}
@@ -284,41 +307,56 @@ class PallasChunkAggregator:
                 np.empty((0, feats.shape[1]), dtype=np.float32),
                 np.empty(0, dtype=np.int64),
             )
-        unique_dst, seg_ids, counts = np.unique(
-            dst, return_inverse=True, return_counts=True
-        )
-        n, d = feats.shape
-        m = len(dst)
-        n_seg = len(unique_dst)
-        if self._blocks is None:
-            self._blocks = auto_blocks(n, d, m, n_seg, self.interpret)
-        be, bv, bdst, bd = self._blocks
-        ep, vp, dp, jp = padded_dims(self._blocks, n, d, m, n_seg)
+        tr = self.tracer
+        with tr.span("dedup", "dedup"):
+            t0 = time.perf_counter()
+            unique_dst, seg_ids, counts = np.unique(
+                dst, return_inverse=True, return_counts=True
+            )
+            n, d = feats.shape
+            m = len(dst)
+            n_seg = len(unique_dst)
+            if self._blocks is None:
+                self._blocks = auto_blocks(n, d, m, n_seg, self.interpret)
+            be, bv, bdst, bd = self._blocks
+            ep, vp, dp, jp = padded_dims(self._blocks, n, d, m, n_seg)
 
-        src_p, dst_p, w_p = self._edges(
-            ep, m, src_local, np.asarray(seg_ids, np.int32), weights
-        )
-        feats_p = self._feats(vp, dp, feats)
+            src_p, dst_p, w_p = self._edges(
+                ep, m, src_local, np.asarray(seg_ids, np.int32), weights
+            )
+            feats_p = self._feats(vp, dp, feats)
+            self.dedup_seconds += time.perf_counter() - t0
 
-        with self.tracer.span("h2d", "h2d"):
-            t0 = time.monotonic()
+        with tr.span("h2d", "h2d"):
+            t0 = time.perf_counter()
             operands = tuple(
                 jax.device_put(x, self.device)
                 for x in (src_p, dst_p, w_p, feats_p)
             )
             jax.block_until_ready(operands)
-            self.h2d_seconds += time.monotonic() - t0
+            self.h2d_seconds += time.perf_counter() - t0
 
-        out = edge_block_spmm_padded(
-            *operands,
-            block_e=be, block_v=bv, block_dst=bdst, block_d=bd,
-            num_dst_padded=jp, interpret=self.interpret,
-        )
+        with tr.span("kernel_wait", "kernel"):
+            t0 = time.perf_counter()
+            out = edge_block_spmm_padded(
+                *operands,
+                block_e=be, block_v=bv, block_dst=bdst, block_d=bd,
+                num_dst_padded=jp, interpret=self.interpret,
+            )
+            out.block_until_ready()
+            self.kernel_wait_seconds += time.perf_counter() - t0
+
+        # the output is ready, so this times the copy alone: the whole
+        # padded [jp, dp] block comes back
+        with tr.span("d2h", "d2h"):
+            t0 = time.perf_counter()
+            host = np.asarray(out)
+            self.d2h_seconds += time.perf_counter() - t0
         # slice on the host: a device-side slice would compile one program
         # per distinct n_seg, i.e. per chunk
         return (
             unique_dst.astype(np.int64),
-            np.asarray(out)[:n_seg, :d],
+            host[:n_seg, :d],
             counts.astype(np.int64),
         )
 

@@ -12,15 +12,21 @@ through its batch API (``add_many`` / ``update_many`` / ``remove_many``),
 so one delivery sub-batch costs a constant number of NumPy calls
 regardless of its size.  The chunk-level eviction shield arrives as a
 boolean mask from the engine (no per-chunk Python sets).
+
+Traffic with the cold store is traced (``evict`` and ``reload`` spans,
+category ``cold``) and timed together into ``evict_seconds``.
 """
 
 from __future__ import annotations
+
+import time
 
 import numpy as np
 
 from repro.core import orchestrator as ost
 from repro.core.eviction import EvictionPolicy
 from repro.core.orchestrator import Orchestrator
+from repro.obs.trace import NULL_TRACER
 from repro.storage.coldstore import ColdStore
 
 
@@ -37,6 +43,7 @@ class MemoryManager:
         orchestrator: Orchestrator,
         policy: EvictionPolicy,
         cold: ColdStore,
+        tracer=None,
     ):
         self.num_slots = num_slots
         self.dim = dim
@@ -44,6 +51,7 @@ class MemoryManager:
         self.orch = orchestrator
         self.policy = policy
         self.cold = cold
+        self.tracer = tracer if tracer is not None else NULL_TRACER
         self.hot = np.zeros((num_slots, dim), dtype=self.dtype)
         self.slot_of = np.full(orchestrator.num_vertices, -1, dtype=np.int64)
         self.vertex_in_slot = np.full(num_slots, -1, dtype=np.int64)
@@ -56,6 +64,7 @@ class MemoryManager:
         self.eviction_count = 0
         self.reload_count = 0
         self.peak_occupancy = 0
+        self.evict_seconds = 0.0  # cold.put + eviction bookkeeping + reload
 
     # ---------------------------------------------------------- occupancy
     @property
@@ -101,14 +110,17 @@ class MemoryManager:
         return self._pop_slots(n)
 
     def _evict(self, victims: np.ndarray) -> None:
-        slots = self.slot_of[victims]
-        self.cold.put(victims, self.hot[slots])
-        self.policy.remove_many(victims)
-        self.orch.to_cold(victims)
-        self.slot_of[victims] = -1
-        self.vertex_in_slot[slots] = -1
-        self._push_slots(slots)
-        self.eviction_count += len(victims)
+        with self.tracer.span("evict", "cold"):
+            t0 = time.perf_counter()
+            slots = self.slot_of[victims]
+            self.cold.put(victims, self.hot[slots])
+            self.policy.remove_many(victims)
+            self.orch.to_cold(victims)
+            self.slot_of[victims] = -1
+            self.vertex_in_slot[slots] = -1
+            self._push_slots(slots)
+            self.eviction_count += len(victims)
+            self.evict_seconds += time.perf_counter() - t0
 
     # ----------------------------------------------------------- activate
     def activate(self, vertices: np.ndarray, chunk_shield=None) -> np.ndarray:
@@ -142,7 +154,10 @@ class MemoryManager:
                 self.policy.add_many(fresh, self.orch.pending(fresh))
             if len(frozen):
                 cslots = slots[k:]
-                self.hot[cslots] = self.cold.take(frozen)
+                with self.tracer.span("reload", "cold"):
+                    t0 = time.perf_counter()
+                    self.hot[cslots] = self.cold.take(frozen)
+                    self.evict_seconds += time.perf_counter() - t0
                 self.slot_of[frozen] = cslots
                 self.vertex_in_slot[cslots] = frozen
                 self.orch.to_hot(frozen)

@@ -17,7 +17,10 @@ contents), is identical to the serial loop.
 
 ``stall_seconds`` is the main thread's wait on the ring (pipeline
 bubble); compare it with the aggregator's ``h2d_seconds`` to see how
-much transfer the overlap actually hides.
+much transfer the overlap actually hides.  The stage thread's own waits
+are traced apart from that stall: ``reader_wait`` (category ``starve``)
+while it waits on the reader for the next chunk, ``ring_put`` (category
+``backpressure``) while the ring is full.
 
 The thread protocol mirrors ``storage.reader.ChunkReader``: bounded
 queue, stop event checked on every timed put, ``None`` sentinel, errors
@@ -34,7 +37,47 @@ from typing import Callable, Iterable, Iterator
 from ..obs.trace import NULL_TRACER
 
 
-class SerialAggregation:
+class _AggregatorCounters:
+    """The round-trip counters of the aggregator a pipeline owns, each
+    0.0 for host-only aggregators like numpy.
+
+    Safe to read after iteration completes: the staged pipeline's
+    generator close (or exhaustion) joins the stage thread, so the
+    worker's last counter update happens-before these reads.
+    """
+
+    def _counter(self, name: str) -> float:
+        return getattr(self._aggregate, name, 0.0)
+
+    @property
+    def dedup_seconds(self) -> float:
+        """Destination dictionary and operand padding on the host."""
+        return self._counter("dedup_seconds")
+
+    @property
+    def h2d_seconds(self) -> float:
+        """Host->device staging of the operands."""
+        return self._counter("h2d_seconds")
+
+    @property
+    def kernel_wait_seconds(self) -> float:
+        """Kernel dispatch plus the blocking wait for its output."""
+        return self._counter("kernel_wait_seconds")
+
+    @property
+    def d2h_seconds(self) -> float:
+        """Device->host copy of the (padded) kernel output."""
+        return self._counter("d2h_seconds")
+
+
+def _next_chunk(chunks: Iterator, tracer):
+    """The reader's next chunk (None when it is exhausted), the wait for
+    it traced as ``reader_wait``."""
+    with tracer.span("reader_wait", "starve"):
+        return next(chunks, None)
+
+
+class SerialAggregation(_AggregatorCounters):
     """Pass-through pipeline: aggregate on the caller's thread.
 
     Same interface as ``StagedAggregation`` (iteration yields
@@ -60,15 +103,10 @@ class SerialAggregation:
         self.aggregate_seconds = 0.0
         self.stall_seconds = 0.0
 
-    @property
-    def h2d_seconds(self) -> float:
-        """Host->device staging time from the aggregator this pipeline
-        owns (0.0 for host-only aggregators like numpy)."""
-        return getattr(self._aggregate, "h2d_seconds", 0.0)
-
     def __iter__(self) -> Iterator:
         tr = self.tracer
-        for chunk in self._chunks:
+        chunks = iter(self._chunks)
+        while (chunk := _next_chunk(chunks, tr)) is not None:
             with tr.span("prep", "prep"):
                 src_local, dst, w = self._prep(chunk)
             with tr.span("aggregate", "aggregate"):
@@ -83,7 +121,7 @@ class SerialAggregation:
             close()
 
 
-class StagedAggregation:
+class StagedAggregation(_AggregatorCounters):
     """Bounded staging ring running prep+aggregate one chunk ahead."""
 
     staged = True
@@ -109,30 +147,22 @@ class StagedAggregation:
         self.aggregate_seconds = 0.0
         self.stall_seconds = 0.0
 
-    @property
-    def h2d_seconds(self) -> float:
-        """Host->device staging time from the pipeline-owned aggregator.
-
-        Safe to read after iteration completes: the generator's close (or
-        exhaustion) joins the stage thread, so the worker's last
-        ``h2d_seconds`` update happens-before this read.
-        """
-        return getattr(self._aggregate, "h2d_seconds", 0.0)
-
     # ------------------------------------------------------ stage thread
     def _put_checked(self, item) -> bool:
-        while not self._stop.is_set():
-            try:
-                self._q.put(item, timeout=0.05)
-                return True
-            except queue.Full:
-                continue
-        return False
+        with self.tracer.span("ring_put", "backpressure"):
+            while not self._stop.is_set():
+                try:
+                    self._q.put(item, timeout=0.05)
+                    return True
+                except queue.Full:
+                    continue
+            return False
 
     def _worker(self) -> None:
         tr = self.tracer
         try:
-            for chunk in self._chunks:
+            chunks = iter(self._chunks)
+            while (chunk := _next_chunk(chunks, tr)) is not None:
                 if self._stop.is_set():
                     break
                 with tr.span("prep", "prep"):

@@ -175,6 +175,7 @@ def run_shard_layer(
         orchestrator=orch,
         policy=policy,
         cold=cold,
+        tracer=tr,
     )
     # per-shard write-back scheduler (None under io_impl='sync'): this
     # worker's own durability domain, barriered before DONE is reported

@@ -143,6 +143,8 @@ def _spmm_call(
         out_specs=pl.BlockSpec((block_dst, block_d), lambda j, k, e, v: (j, k)),
         out_shape=jax.ShapeDtypeStruct((num_dst_padded, dp), jnp.float32),
         interpret=interpret,
+        # a stable name for the kernel's device ops in a profiler trace
+        name="edge_block_spmm",
     )(src_p, dst_p, w_p, feats_p)
 
 

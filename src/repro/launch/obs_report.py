@@ -8,7 +8,9 @@ Loads a Chrome trace-event file exported by ``repro.obs.trace.Tracer``
   not double-counted),
 * overlap efficiency — how much offloaded work (read / spill / fsync /
   graduation / transform) ran concurrently with the delivery thread,
-  and the pipeline bubble % (delivery-thread stalls / layer wall),
+  the pipeline bubble % (delivery-thread stalls / layer wall) and the
+  stage-wait % (the staging thread blocked on a full ring or waiting on
+  the reader / layer wall),
 * the dominant bottleneck category.
 
 ``--check`` validates the trace-event schema (well-formed ``ph``/``ts``/
@@ -34,12 +36,24 @@ import sys
 # tries to hide behind delivery (vs. inline main-thread categories)
 OFFLOADED_CATS = ("read", "spill", "fsync", "barrier", "drain", "sink")
 
-# LayerMetrics field <- trace categories it should reconcile with
-# (self-time totals; a parent category lists the children carved out of
-# it so parent_self + children == the scalar's timed region)
+# waits of the staging thread: a full ring (the delivery thread is
+# behind) and an empty reader queue (the reader is behind).  Kept apart
+# from "stall", which is the delivery thread's wait on the ring.
+STAGE_WAIT_CATS = ("backpressure", "starve")
+
+# LayerMetrics field <- trace categories it should reconcile with: the
+# self time of spans of those categories plus that of any span nested
+# inside one of them (a parent category lists the children carved out
+# of it; nested spans of other categories, such as the graduation
+# hand-off inside "deliver", belong to the parent's timed region too)
 RECONCILE = {
-    "aggregate_seconds": ("aggregate", "h2d"),
+    "aggregate_seconds": ("aggregate", "h2d", "dedup", "kernel", "d2h"),
     "h2d_seconds": ("h2d",),
+    "dedup_seconds": ("dedup",),
+    "kernel_wait_seconds": ("kernel",),
+    "d2h_seconds": ("d2h",),
+    "deliver_seconds": ("deliver", "cold"),
+    "evict_seconds": ("cold",),
     "pipeline_stall_seconds": ("stall",),
     "transform_seconds": ("transform",),
     "barrier_seconds": ("barrier", "fsync"),
@@ -131,7 +145,8 @@ def validate_trace(events: list[dict]) -> list[str]:
 
 
 def extract_spans(events: list[dict]) -> tuple[list[dict], dict[int, str]]:
-    """Matched spans (with self time) + tid -> thread-name map."""
+    """Matched spans (with self time and the categories of the spans
+    enclosing them, ``outer``) + tid -> thread-name map."""
     names: dict[int, str] = {}
     spans: list[dict] = []
     stacks: dict[tuple, list[list]] = {}
@@ -156,6 +171,7 @@ def extract_spans(events: list[dict]) -> tuple[list[dict], dict[int, str]]:
                 "tid": ev.get("tid"), "name": name, "cat": cat,
                 "start_us": ts0, "dur_us": dur,
                 "self_us": max(0.0, dur - child),
+                "outer": frozenset(c for _, c, _, _ in stack),
             })
     return spans, names
 
@@ -181,6 +197,7 @@ def analyze(events: list[dict]) -> dict:
             cats[s["cat"]] = cats.get(s["cat"], 0.0) + s["self_us"] / 1e6
         offloaded = sum(cats.get(c, 0.0) for c in OFFLOADED_CATS)
         stall = cats.get("stall", 0.0)
+        stage_wait = sum(cats.get(c, 0.0) for c in STAGE_WAIT_CATS)
         dominant = max(cats, key=cats.get) if cats else None
         layers.append({
             "name": ls["name"],
@@ -192,17 +209,24 @@ def analyze(events: list[dict]) -> dict:
             # with several busy offload threads
             "overlap_ratio": offloaded / wall_s if wall_s else 0.0,
             "bubble_pct": 100.0 * stall / wall_s if wall_s else 0.0,
+            "stage_wait_pct": 100.0 * stage_wait / wall_s if wall_s else 0.0,
             "dominant": dominant,
         })
     total_cats: dict[str, float] = {}
+    # self time by the categories of a span and of the spans around it
+    # ("+"-joined): what ``reconcile`` sums a field's timed region from
+    regions: dict[str, float] = {}
     for s in spans:
         total_cats[s["cat"]] = total_cats.get(s["cat"], 0.0) + s["self_us"] / 1e6
+        key = "+".join(sorted(s["outer"] | {s["cat"]}))
+        regions[key] = regions.get(key, 0.0) + s["self_us"] / 1e6
     return {
         "num_events": len(events),
         "num_spans": len(spans),
         "threads": {str(t): n for t, n in sorted(names.items())},
         "layers": layers,
         "category_seconds": dict(sorted(total_cats.items())),
+        "region_seconds": dict(sorted(regions.items())),
     }
 
 
@@ -215,10 +239,11 @@ def reconcile(report: dict, layer_metrics: list[dict],
     below ``floor_s`` are skipped: at sub-5ms scale, span-begin/end
     overhead and clock jitter dominate the comparison."""
     problems: list[str] = []
-    trace_cats = report["category_seconds"]
+    regions = [(set(key.split("+")), sec)
+               for key, sec in report["region_seconds"].items()]
     for field, cats in RECONCILE.items():
         metric = sum(float(m.get(field, 0.0)) for m in layer_metrics)
-        traced = sum(trace_cats.get(c, 0.0) for c in cats)
+        traced = sum(sec for region, sec in regions if region & set(cats))
         if metric < floor_s and traced < floor_s:
             continue
         ref = max(metric, floor_s)
@@ -250,18 +275,19 @@ def print_report(report: dict, out=sys.stdout) -> None:
         p(f"\n{layer['name']}  wall {_fmt_seconds(layer['wall_seconds'])}"
           f"  overlap {layer['overlap_ratio']:.2f}x"
           f"  bubble {layer['bubble_pct']:.1f}%"
+          f"  stage wait {layer['stage_wait_pct']:.1f}%"
           f"  bottleneck: {layer['dominant']}")
         for cat, sec in sorted(
             layer["category_seconds"].items(), key=lambda kv: -kv[1]
         ):
             share = sec / layer["wall_seconds"] if layer["wall_seconds"] else 0
-            p(f"    {cat:<10} {_fmt_seconds(sec)}  {share:6.1%} of wall")
+            p(f"    {cat:<12} {_fmt_seconds(sec)}  {share:6.1%} of wall")
     if not report["layers"]:
         p("\n(no layer spans — run totals only)")
         for cat, sec in sorted(
             report["category_seconds"].items(), key=lambda kv: -kv[1]
         ):
-            p(f"    {cat:<10} {_fmt_seconds(sec)}")
+            p(f"    {cat:<12} {_fmt_seconds(sec)}")
 
 
 def _load_layer_metrics(path: str) -> list[dict]:

@@ -13,10 +13,9 @@ Enable per-run via ``AtlasConfig(trace=True)`` or
 
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
 from .sampler import ResourceSampler
-from .trace import CATEGORIES, NULL_TRACER, NullTracer, Tracer, as_tracer
+from .trace import NULL_TRACER, NullTracer, Tracer, as_tracer
 
 __all__ = [
-    "CATEGORIES",
     "Counter",
     "Gauge",
     "Histogram",

@@ -23,26 +23,19 @@ Design constraints, in order:
    ids at registration, so short-lived helper threads (the per-layer
    reader / barrier threads) never collide on a recycled OS thread id.
 3. **Faithful to the metrics.**  Spans are placed around the *same*
-   timed regions that feed ``LayerMetrics`` (aggregate, h2d, tail,
-   spill, fsync, barrier, stall), so per-category span totals reconcile
-   with the scalar fields — ``repro.launch.obs_report`` checks this.
+   timed regions that feed ``LayerMetrics`` (aggregate, dedup, h2d,
+   kernel wait, d2h, deliver, evict/reload, tail, spill, fsync, barrier,
+   stall), so per-category span totals reconcile with the scalar
+   fields — ``repro.launch.obs_report`` checks this.
 
-Span categories used by the engine/serving instrumentation::
+Every span and category the engine and serving paths record, and the
+``LayerMetrics`` field or benchmark metric each feeds, is listed in
+``docs/observability.md``.
 
-    read       chunk reads (reader thread) / serving block fetches
-    aggregate  chunk_aggregate() calls (staging or delivery thread)
-    h2d        host->device staging inside the jax/pallas aggregators
-    prep       per-chunk edge prep (weights, local ids)
-    tail       graduation buffering + writer scatter (bookkeeping)
-    transform  the dense layer update (W.x + b + sigma)
-    sink       hand-off from the graduation thread to the writer queue
-    spill      spill serialization: write_spill / submit_spill cost
-    fsync      group-commit fsync pass (files + dirs)
-    barrier    write-back queue drain + the layer group commit
-    stall      waits on a pipeline ring / buffer backpressure
-    serve      VertexQueryEngine lookups and cache traffic
-    layer      one whole run_layer invocation (the bucketing window)
-    sample     resource-sampler counter track (RSS, disk bytes)
+Profiler clock: while a ``Tracer`` is enabled, each span also enters a
+``jax.profiler.TraceAnnotation`` of the same name on its thread, so a
+run captured with the JAX profiler carries the program's spans on the
+profiler's host plane, on the same clock as the device's operations.
 
 Nesting: ``span()`` is a context manager; spans on one thread must be
 strictly nested (guaranteed by ``with`` scoping), which the exporter
@@ -56,10 +49,7 @@ import os
 import threading
 import time
 
-CATEGORIES = (
-    "read", "aggregate", "h2d", "prep", "tail", "transform", "sink",
-    "spill", "fsync", "barrier", "stall", "serve", "layer", "sample",
-)
+from jax.profiler import TraceAnnotation
 
 
 class _Span:
@@ -114,9 +104,6 @@ class NullTracer:
     def end(self, name: str, cat: str) -> None:
         pass
 
-    def instant(self, name: str, cat: str = "layer") -> None:
-        pass
-
     def counter(self, name: str, value: float, cat: str = "sample") -> None:
         pass
 
@@ -158,7 +145,7 @@ class _ThreadBuf:
     id assigned at registration — stable even when the OS recycles thread
     idents across short-lived helper threads."""
 
-    __slots__ = ("track", "name", "events")
+    __slots__ = ("track", "name", "events", "annotations")
 
     def __init__(self, track: int, name: str):
         self.track = track
@@ -166,6 +153,9 @@ class _ThreadBuf:
         # (ph, ts_ns, name, cat, value-or-None) appended lock-free by the
         # owning thread; value is only set for counter ('C') events
         self.events: list[tuple] = []
+        # the profiler annotations of this thread's open spans, innermost
+        # last (spans on one thread nest, so end() pops the innermost)
+        self.annotations: list = []
 
 
 class Tracer:
@@ -201,20 +191,25 @@ class Tracer:
     def span(self, name: str, cat: str) -> _Span:
         return _Span(self, name, cat)
 
+    # The profiler annotation opens just before the span's clock read and
+    # closes just after it, with no Python frame in between, so the two
+    # agree to about a microsecond.
     def begin(self, name: str, cat: str) -> None:
-        self._buf().events.append(
+        buf = self._buf()
+        annotation = TraceAnnotation(name)
+        annotation.__enter__()
+        buf.annotations.append(annotation)
+        buf.events.append(
             ("B", time.perf_counter_ns() - self.t0_ns, name, cat, None)
         )
 
     def end(self, name: str, cat: str) -> None:
-        self._buf().events.append(
+        buf = self._buf()
+        buf.events.append(
             ("E", time.perf_counter_ns() - self.t0_ns, name, cat, None)
         )
-
-    def instant(self, name: str, cat: str = "layer") -> None:
-        self._buf().events.append(
-            ("i", time.perf_counter_ns() - self.t0_ns, name, cat, None)
-        )
+        if buf.annotations:
+            buf.annotations.pop().__exit__(None, None, None)
 
     def counter(self, name: str, value: float, cat: str = "sample") -> None:
         """A counter sample — rendered by Perfetto as a value track
@@ -254,8 +249,6 @@ class Tracer:
                 }
                 if ph == "C":
                     rec["args"] = {"value": value}
-                elif ph == "i":
-                    rec["s"] = "t"  # instant scope: thread
                 out.append(rec)
         return out
 
@@ -298,8 +291,11 @@ class Tracer:
         """Write the Chrome trace-event JSON (Perfetto-loadable)
         atomically; returns ``path``."""
         tmp = path + ".tmp"
+        # one dumps() call runs the C encoder; dump() would stream the
+        # document through the pure-Python one, about 3x slower
+        text = json.dumps(self.to_chrome())
         with open(tmp, "w") as f:
-            json.dump(self.to_chrome(), f)
+            f.write(text)
         os.replace(tmp, path)
         return path
 
@@ -338,7 +334,6 @@ def merge_trace_files(paths: list[str], out_path: str) -> str:
 
 
 __all__ = [
-    "CATEGORIES",
     "NULL_TRACER",
     "NullTracer",
     "Tracer",

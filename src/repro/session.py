@@ -499,8 +499,10 @@ class AtlasSession:
                 store_digest=store.ordering_digest,
             )
 
-        csr = store.topology()
-        in_deg, _ = degrees_from_csr(csr)
+        tr = self.tracer
+        with tr.span("open_run", "session"):
+            csr = store.topology()
+            in_deg, _ = degrees_from_csr(csr)
         metrics: list[LayerMetrics] = []
         layers: dict[int, LayerHandle] = {}
         spills = store.layer0_spills()
@@ -539,7 +541,8 @@ class AtlasSession:
                 # discard partial output of a crashed attempt at this layer
                 out_dir = os.path.join(self.workdir, f"layer_{l + 1}")
                 if os.path.exists(out_dir):
-                    shutil.rmtree(out_dir)
+                    with tr.span("clear_layer_dir", "session"):
+                        shutil.rmtree(out_dir)
                 # the previous layer's commit (barrier-wait -> manifest
                 # advance -> spill GC) rides into run_layer, which calls
                 # it after its own pipeline has started — the group
@@ -549,27 +552,29 @@ class AtlasSession:
                     scheduler=scheduler, pending_commit=pending_commit,
                     tracer=self.tracer,
                 )
-                metrics.append(m)
-                pending_commit = self._layer_commit(
-                    manifest, manifest_path, l, layer_spills, barrier_wait,
-                    spills, layers, scheduler,
-                )
-                spills = layer_spills
-                layers[l + 1] = self._handle(
-                    l + 1, layer_spills, specs[l].out_dim
-                )
-            if pending_commit is not None:
-                pending_commit()
-            if scheduler is not None:
-                # the final manifest write deferred its fsync to the next
-                # group commit — this is it
-                scheduler.barrier()
-                # the run-wide I/O accounting, captured at its final
-                # (post-last-barrier, pre-close) state — the close below
-                # only reclaims the I/O thread
-                queue_stats = scheduler.qstats.snapshot()
-                scheduler.close(commit=False)
-                self._io_sched = None
+                with tr.span("handoff", "session"):
+                    metrics.append(m)
+                    pending_commit = self._layer_commit(
+                        manifest, manifest_path, l, layer_spills,
+                        barrier_wait, spills, layers, scheduler,
+                    )
+                    spills = layer_spills
+                    layers[l + 1] = self._handle(
+                        l + 1, layer_spills, specs[l].out_dim
+                    )
+            with tr.span("close_run", "session"):
+                if pending_commit is not None:
+                    pending_commit()
+                if scheduler is not None:
+                    # the final manifest write deferred its fsync to the
+                    # next group commit — this is it
+                    scheduler.barrier()
+                    # the run-wide I/O accounting, captured at its final
+                    # (post-last-barrier, pre-close) state — the close
+                    # below only reclaims the I/O thread
+                    queue_stats = scheduler.qstats.snapshot()
+                    scheduler.close(commit=False)
+                    self._io_sched = None
         except BaseException:
             # the last *finished* layer's commit may still be pending
             # (its data is complete; only barrier+manifest were deferred)
@@ -598,7 +603,8 @@ class AtlasSession:
             manifest=manifest, metrics=metrics, layers=layers,
             queue_stats=queue_stats,
         )
-        result.telemetry = self._telemetry(metrics, queue_stats, sampler)
+        with tr.span("telemetry", "session"):
+            result.telemetry = self._telemetry(metrics, queue_stats, sampler)
         if self.tracer.enabled:
             result.trace_path = self.tracer.export(
                 os.path.join(self.workdir, "trace.json")
