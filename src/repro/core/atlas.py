@@ -130,9 +130,13 @@ class LayerMetrics:
     h2d_seconds: float = 0.0  # host->device staging (jax/pallas backends)
     pipeline_stall_seconds: float = 0.0  # delivery thread waits on the ring
     # the rest of the aggregate call's round trip (jax/pallas backends)
-    dedup_seconds: float = 0.0  # np.unique, segment ids, operand padding
+    dedup_seconds: float = 0.0  # sort by dst, segment ids, padding, items
     kernel_wait_seconds: float = 0.0  # kernel call + wait for its output
     d2h_seconds: float = 0.0  # copy of the padded output to the host
+    # aggregation kernel grid steps (pallas backend): run, and what a
+    # grid over every (destination tile, edge block) pair would run
+    spmm_steps: int = 0
+    spmm_dense_steps: int = 0
     # the delivery thread's per-chunk work
     deliver_seconds: float = 0.0  # shield set -> both _deliver -> cleared
     evict_seconds: float = 0.0  # cold-store eviction and reload
@@ -560,6 +564,8 @@ class AtlasEngine:
             dedup_seconds=pipe.dedup_seconds,
             kernel_wait_seconds=pipe.kernel_wait_seconds,
             d2h_seconds=pipe.d2h_seconds,
+            spmm_steps=pipe.spmm_steps,
+            spmm_dense_steps=pipe.spmm_dense_steps,
             deliver_seconds=deliver_seconds,
             evict_seconds=mm.evict_seconds,
         )

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from repro.kernels.edge_block_spmm import (
     auto_blocks,
     edge_block_spmm_padded,
+    work_items,
 )
 from repro.obs.trace import NULL_TRACER
 
@@ -216,10 +217,14 @@ def padded_dims(
 class PallasChunkAggregator:
     """Pallas ``edge_block_spmm`` as a chunk_aggregate backend.
 
-    Host side mirrors the jax backend: ``np.unique`` builds the chunk's
-    destination dictionary, so the kernel runs over *dense* segment ids
+    Host side builds the chunk's destination dictionary from one stable
+    sort of the destinations, so the kernel runs over *dense* segment ids
     (``num_dst = n_seg``) instead of global vertex ids — the out tile
-    count tracks the chunk's fan-out, not |V|.
+    count tracks the chunk's fan-out, not |V| — and gets its edges in
+    segment order, which its grid over (destination tile, edge block)
+    items needs (``work_items``).  ``spmm_steps`` counts the grid steps
+    run (padding items excluded), ``spmm_dense_steps`` the steps a grid
+    over every such pair would run at the same padded shapes.
 
     Chunk-to-chunk reuse: operand padding happens in host scratch buffers
     keyed by padded shape (allocated once per bucket, refilled per call;
@@ -265,11 +270,15 @@ class PallasChunkAggregator:
         self.h2d_seconds = 0.0
         self.kernel_wait_seconds = 0.0
         self.d2h_seconds = 0.0
+        self.spmm_steps = 0
+        self.spmm_dense_steps = 0
         self.tracer = NULL_TRACER
         self._feat_scratch: dict[tuple[int, int], np.ndarray] = {}
         self._edge_scratch: dict[int, tuple[np.ndarray, np.ndarray, np.ndarray]] = {}
 
-    def _edges(self, ep: int, m: int, src_local, seg_ids, weights):
+    def _edges(self, ep: int, m: int, order, src_local, seg_ids, weights):
+        """The edges in segment order (``order`` sorts them) in the
+        scratch buffers of bucket ``ep``, padding margins reset."""
         buf = self._edge_scratch.get(ep)
         if buf is None:
             buf = (
@@ -279,11 +288,11 @@ class PallasChunkAggregator:
             )
             self._edge_scratch[ep] = buf
         src_p, dst_p, w_p = buf
-        src_p[:m, 0] = src_local
+        src_p[:m, 0] = src_local[order]
         src_p[m:, 0] = -1
         dst_p[:m, 0] = seg_ids
         dst_p[m:, 0] = -1
-        w_p[:m, 0] = weights
+        w_p[:m, 0] = weights[order]
         w_p[m:, 0] = 0.0
         return src_p, dst_p, w_p
 
@@ -310,28 +319,42 @@ class PallasChunkAggregator:
         tr = self.tracer
         with tr.span("dedup", "dedup"):
             t0 = time.perf_counter()
-            unique_dst, seg_ids, counts = np.unique(
-                dst, return_inverse=True, return_counts=True
-            )
+            # what np.unique does inside, keeping the sort: segment ids
+            # ascend in edge order, and ties keep their edge order
+            order = np.argsort(dst, kind="stable")
+            sdst = dst[order]
             n, d = feats.shape
             m = len(dst)
-            n_seg = len(unique_dst)
+            new_seg = np.empty(m, bool)
+            new_seg[0] = True
+            np.not_equal(sdst[1:], sdst[:-1], out=new_seg[1:])
+            starts = np.flatnonzero(new_seg)
+            unique_dst = sdst[starts]
+            counts = np.diff(np.append(starts, m))
+            n_seg = len(starts)
+            seg_ids = np.cumsum(new_seg, dtype=np.int32) - 1
             if self._blocks is None:
                 self._blocks = auto_blocks(n, d, m, n_seg, self.interpret)
             be, bv, bdst, bd = self._blocks
             ep, vp, dp, jp = padded_dims(self._blocks, n, d, m, n_seg)
 
             src_p, dst_p, w_p = self._edges(
-                ep, m, src_local, np.asarray(seg_ids, np.int32), weights
+                ep, m, order, src_local, seg_ids, weights
             )
             feats_p = self._feats(vp, dp, feats)
+            items = work_items(
+                seg_ids, n_seg, block_e=be, block_dst=bdst, ep=ep, jp=jp
+            )
+            per_item = (dp // bd) * (vp // bv)
+            self.spmm_steps += int(items[0]) * per_item
+            self.spmm_dense_steps += (jp // bdst) * (ep // be) * per_item
             self.dedup_seconds += time.perf_counter() - t0
 
         with tr.span("h2d", "h2d"):
             t0 = time.perf_counter()
             operands = tuple(
                 jax.device_put(x, self.device)
-                for x in (src_p, dst_p, w_p, feats_p)
+                for x in (items, src_p, dst_p, w_p, feats_p)
             )
             jax.block_until_ready(operands)
             self.h2d_seconds += time.perf_counter() - t0
@@ -347,7 +370,8 @@ class PallasChunkAggregator:
             self.kernel_wait_seconds += time.perf_counter() - t0
 
         # the output is ready, so this times the copy alone: the whole
-        # padded [jp, dp] block comes back
+        # padded [jp, dp] block comes back (rows past the last segment's
+        # tile are never written, and never read)
         with tr.span("d2h", "d2h"):
             t0 = time.perf_counter()
             host = np.asarray(out)

@@ -39,15 +39,15 @@ from ..obs.trace import NULL_TRACER
 
 class _AggregatorCounters:
     """The round-trip counters of the aggregator a pipeline owns, each
-    0.0 for host-only aggregators like numpy.
+    0 for host-only aggregators like numpy.
 
     Safe to read after iteration completes: the staged pipeline's
     generator close (or exhaustion) joins the stage thread, so the
     worker's last counter update happens-before these reads.
     """
 
-    def _counter(self, name: str) -> float:
-        return getattr(self._aggregate, name, 0.0)
+    def _counter(self, name: str, default: float = 0.0):
+        return getattr(self._aggregate, name, default)
 
     @property
     def dedup_seconds(self) -> float:
@@ -68,6 +68,17 @@ class _AggregatorCounters:
     def d2h_seconds(self) -> float:
         """Device->host copy of the (padded) kernel output."""
         return self._counter("d2h_seconds")
+
+    @property
+    def spmm_steps(self) -> int:
+        """Grid steps the aggregation kernel ran (padding items out)."""
+        return self._counter("spmm_steps", 0)
+
+    @property
+    def spmm_dense_steps(self) -> int:
+        """Grid steps a kernel over every (destination tile, edge block)
+        pair would have run at the same padded shapes."""
+        return self._counter("spmm_dense_steps", 0)
 
 
 def _next_chunk(chunks: Iterator, tracer):

@@ -9,35 +9,59 @@ TPU-idiomatic form (DESIGN.md §2) is the **one-hot MXU formulation**:
 
 Both one-hots are built on the fly from an iota comparison (never stored
 in HBM).  The kernel tiles edges (Eb), source rows (Vt), destination rows
-(DstT) and the feature dim (Db); the out block [DstT, Db] is revisited and
-accumulated across the two inner grid axes (edge blocks x source tiles),
-which is exactly a blocked SpMM reduction.
+(DstT) and the feature dim (Db).
 
-Grid: (dst_tiles, d_tiles, e_blocks, src_tiles)   — last axis innermost.
-Padding edges carry src = dst = -1, whose one-hot rows are all-zero, so
-they contribute nothing (no masking needed).
+Edges arrive **sorted by destination**, so each destination tile owns a
+contiguous run of edge blocks.  The grid runs over *work items*, one per
+(destination tile, edge block) pair that holds at least one of the
+tile's edges, in the style of a grouped matmul:
+
+    grid = (d_tiles, items, src_tiles)   — last axis innermost
+
+The item tables (``work_items``) arrive by scalar prefetch and steer the
+BlockSpecs: the edge blocks read ``block_of[i]``, the out block is
+``(tile_of[i], k)``.  Items are sorted by tile and the d tiles are
+outermost, so an out block is revisited only on consecutive steps: it
+accumulates in VMEM, is zeroed on its tile's first item (``first[i]`` and
+``v == 0``) and written back once.  An edge of the block that belongs to
+a neighbouring tile has an all-zero dst one-hot row, as the ``-1``
+padding sentinel has (``src = dst = -1``, ``w = 0``).
+
+Steps: ``items x d_tiles x src_tiles`` with ``items <= e_blocks +
+dst_tiles``, against ``dst_tiles x e_blocks x d_tiles x src_tiles`` for a
+grid over every pair.  The item count is padded to that static bound
+(``max_work_items``), so the compiled programs depend on the padded
+shapes alone; padding items repeat the last real item (no new DMA, no
+extra write-back) and are skipped.  Output tiles past the last tile that
+holds an edge are never visited and hold whatever the buffer held:
+callers read only the first ``num_dst`` rows.
 
 VMEM working set per step (defaults Eb=256, Vt=1024, DstT=256, Db=128,
 fp32): feats 512 KiB + src-onehot 1 MiB + dst-onehot 256 KiB + msgs
-128 KiB + out 128 KiB ≈ 2 MiB — comfortably inside the ~16 MiB/core VMEM,
-and every matmul dim is a multiple of the 128-lane MXU tile.
+128 KiB + out 128 KiB ≈ 2 MiB —
+comfortably inside the ~16 MiB/core VMEM, and every matmul dim is a
+multiple of the 128-lane MXU tile.  The item tables sit in SMEM.
 
 Entry points:
 
-* ``edge_block_spmm`` — the general API: pads each operand only when its
-  shape is not already block-aligned (an aligned call does **zero**
-  device-side copies, fixing the old always-materialize-(vp, dp) cost),
-  and picks block sizes with ``auto_blocks`` when none are given.
+* ``edge_block_spmm`` — the general API: sorts the edges by destination
+  on the host, builds the item tables, pads each operand to its blocks
+  (features only when not already aligned) and picks block sizes with
+  ``auto_blocks`` when none are given.
 * ``edge_block_spmm_padded`` — the jitted pre-aligned fast path used by
-  ``core.broadcast.PallasChunkAggregator``, which pads on the host into
-  reused scratch buffers and ships them with one ``device_put`` each.
+  ``core.broadcast.PallasChunkAggregator``, which sorts and pads on the
+  host into reused scratch buffers and ships them with one
+  ``device_put`` each.
 """
 
 from __future__ import annotations
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _round_up(x: int, m: int) -> int:
@@ -70,53 +94,118 @@ def auto_blocks(
     return block_e, block_v, block_dst, block_d
 
 
+def max_work_items(ep: int, jp: int, block_e: int, block_dst: int) -> int:
+    """Static bound on the work items of ``ep`` padded edges into ``jp``
+    padded destination rows: consecutive tiles share at most one edge
+    block, so the pairs that hold an edge number at most ``e_blocks +
+    dst_tiles``."""
+    return ep // block_e + jp // block_dst
+
+
+def work_items(
+    dst_sorted: np.ndarray,  # [m] destinations, ascending
+    num_dst: int,
+    *,
+    block_e: int,
+    block_dst: int,
+    ep: int,
+    jp: int,
+) -> np.ndarray:
+    """The kernel's item tables for edges sorted by destination.
+
+    Returns one int32 array ``[1 + 3 L]``, ``L = max_work_items(...)``:
+    ``[n, tile_of[L], block_of[L], first[L]]``.  Items ``0..n-1`` are the
+    (destination tile, edge block) pairs that hold an edge, sorted by
+    tile then block, for every tile of ``num_dst`` rows; ``first`` flags
+    a tile's first item.  A tile with no edge still gets one item (on
+    the block where its edges would start), so its rows are zeroed.
+    Items ``n..L-1`` repeat item ``n-1`` with ``first = 0``.  Edges with
+    a destination below 0 or at or past ``num_dst`` fall in no tile.
+    """
+    num_items = max_work_items(ep, jp, block_e, block_dst)
+    e_blocks = ep // block_e
+    n_tiles = -(-num_dst // block_dst)
+    bounds = np.searchsorted(
+        dst_sorted, np.arange(n_tiles + 1, dtype=np.int64) * block_dst
+    )
+    lo, hi = bounds[:-1], bounds[1:]
+    b0 = np.minimum(lo // block_e, e_blocks - 1)
+    b1 = np.maximum((hi - 1) // block_e, b0)
+    count = b1 - b0 + 1
+    n = int(count.sum())
+    tile_of = np.repeat(np.arange(n_tiles), count)
+    starts = np.cumsum(count) - count
+    items = np.empty(1 + 3 * num_items, np.int32)
+    items[0] = n
+    tiles = items[1:1 + num_items]
+    blocks = items[1 + num_items:1 + 2 * num_items]
+    first = items[1 + 2 * num_items:]
+    tiles[:n] = tile_of
+    blocks[:n] = b0[tile_of] + np.arange(n) - starts[tile_of]
+    first[:n] = 0
+    first[starts] = 1
+    tiles[n:] = tiles[n - 1]
+    blocks[n:] = blocks[n - 1]
+    first[n:] = 0
+    return items
+
+
 def _spmm_kernel(
-    src_ref,  # [Eb, 1] int32 (block over e)
+    items_ref,  # SMEM int32 [1 + 3 L]: n, tile_of, block_of, first
+    src_ref,  # [Eb, 1] int32 (edge block block_of[i])
     dst_ref,  # [Eb, 1] int32
     w_ref,  # [Eb, 1] f32
     feats_ref,  # [Vt, Db]
-    out_ref,  # [DstT, Db] f32 accumulator (revisited over e, v)
+    out_ref,  # [DstT, Db] f32 accumulator (revisited over i, v)
 ):
-    j = pl.program_id(0)  # dst tile
-    e = pl.program_id(2)  # edge block
-    v = pl.program_id(3)  # src tile
-    dst_t, db = out_ref.shape
+    i = pl.program_id(1)  # work item
+    v = pl.program_id(2)  # src tile
+    num_items = (items_ref.shape[0] - 1) // 3
+    dst_t = out_ref.shape[0]
     vt = feats_ref.shape[0]
 
-    @pl.when((e == 0) & (v == 0))
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+    @pl.when(i < items_ref[0])  # padding items do nothing
+    def _step():
+        j = items_ref[1 + i]  # dst tile
 
-    src = src_ref[:, 0]
-    dst = dst_ref[:, 0]
-    w = w_ref[:, 0]
+        @pl.when((items_ref[1 + 2 * num_items + i] == 1) & (v == 0))
+        def _init():
+            out_ref[...] = jnp.zeros_like(out_ref)
 
-    # gather: one-hot over this source tile (rows outside the tile -> 0)
-    v_ids = v * vt + jax.lax.broadcasted_iota(jnp.int32, (src.shape[0], vt), 1)
-    src_oh = (src[:, None] == v_ids).astype(jnp.float32)
-    # HIGHEST: f32 contraction on the MXU; a bf16 pass would round the
-    # features past the engine's 1e-4 error bound against the reference
-    msgs = jnp.dot(
-        src_oh, feats_ref[...].astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32,
-    )
-    msgs = msgs * w[:, None]
+        src = src_ref[:, 0]
+        dst = dst_ref[:, 0]
+        w = w_ref[:, 0]
 
-    # scatter: one-hot over this destination tile, transposed GEMM
-    j_ids = j * dst_t + jax.lax.broadcasted_iota(
-        jnp.int32, (dst.shape[0], dst_t), 1
-    )
-    dst_oh = (dst[:, None] == j_ids).astype(jnp.float32)
-    out_ref[...] += jnp.dot(
-        dst_oh.T, msgs, precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32,
-    )
+        # gather: one-hot over this source tile (rows outside the tile -> 0)
+        v_ids = v * vt + jax.lax.broadcasted_iota(
+            jnp.int32, (src.shape[0], vt), 1
+        )
+        src_oh = (src[:, None] == v_ids).astype(jnp.float32)
+        # HIGHEST: f32 contraction on the MXU; a bf16 pass would round the
+        # features past the engine's 1e-4 error bound against the reference
+        msgs = jnp.dot(
+            src_oh, feats_ref[...].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+        msgs = msgs * w[:, None]
+
+        # scatter: one-hot over this destination tile, transposed GEMM;
+        # the block's edges of other tiles match no column
+        j_ids = j * dst_t + jax.lax.broadcasted_iota(
+            jnp.int32, (dst.shape[0], dst_t), 1
+        )
+        dst_oh = (dst[:, None] == j_ids).astype(jnp.float32)
+        out_ref[...] += jnp.dot(
+            dst_oh.T, msgs, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
 
 
 def _spmm_call(
-    src_p,  # [ep, 1] int32, -1 sentinel padding
-    dst_p,  # [ep, 1] int32, -1 sentinel padding
+    items,  # [1 + 3 L] int32, from work_items
+    src_p,  # [ep, 1] int32, sorted by dst, -1 sentinel padding
+    dst_p,  # [ep, 1] int32, ascending, -1 sentinel padding
     w_p,  # [ep, 1] f32, zero padding
     feats_p,  # [vp, dp]
     *,
@@ -127,25 +216,33 @@ def _spmm_call(
     num_dst_padded: int,
     interpret: bool,
 ) -> jax.Array:
-    ep = src_p.shape[0]
     vp, dp = feats_p.shape
-    grid = (num_dst_padded // block_dst, dp // block_d, ep // block_e,
-            vp // block_v)
+    num_items = (items.shape[0] - 1) // 3
+
+    def edge_block(k, i, v, items_ref):
+        return items_ref[1 + num_items + i], 0
+
+    def out_block(k, i, v, items_ref):
+        return items_ref[1 + i], k
+
+    edge_spec = pl.BlockSpec((block_e, 1), edge_block)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(dp // block_d, num_items, vp // block_v),
+        in_specs=[
+            edge_spec, edge_spec, edge_spec,
+            pl.BlockSpec((block_v, block_d), lambda k, i, v, items_ref: (v, k)),
+        ],
+        out_specs=pl.BlockSpec((block_dst, block_d), out_block),
+    )
     return pl.pallas_call(
         _spmm_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_e, 1), lambda j, k, e, v: (e, 0)),
-            pl.BlockSpec((block_e, 1), lambda j, k, e, v: (e, 0)),
-            pl.BlockSpec((block_e, 1), lambda j, k, e, v: (e, 0)),
-            pl.BlockSpec((block_v, block_d), lambda j, k, e, v: (v, k)),
-        ],
-        out_specs=pl.BlockSpec((block_dst, block_d), lambda j, k, e, v: (j, k)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_dst_padded, dp), jnp.float32),
         interpret=interpret,
         # a stable name for the kernel's device ops in a profiler trace
         name="edge_block_spmm",
-    )(src_p, dst_p, w_p, feats_p)
+    )(items, src_p, dst_p, w_p, feats_p)
 
 
 _STATIC = ("block_e", "block_v", "block_dst", "block_d", "num_dst_padded",
@@ -154,6 +251,7 @@ _spmm_jit = jax.jit(_spmm_call, static_argnames=_STATIC)
 
 
 def edge_block_spmm_padded(
+    items: jax.Array,
     src_p: jax.Array,
     dst_p: jax.Array,
     w_p: jax.Array,
@@ -167,10 +265,13 @@ def edge_block_spmm_padded(
     interpret: bool = False,
 ) -> jax.Array:
     """Pre-aligned fast path: every operand already a block multiple,
-    edge padding carries ``src = dst = -1`` and ``w = 0``.  Returns the
-    padded ``[num_dst_padded, dp]`` accumulator (slice it yourself)."""
+    edges sorted by destination, edge padding carries ``src = dst = -1``
+    and ``w = 0``, ``items`` from ``work_items`` over the same edges.
+    Returns the padded ``[num_dst_padded, dp]`` accumulator, whose rows
+    past the last tile that holds an edge are not written (slice it
+    yourself)."""
     return _spmm_jit(
-        src_p, dst_p, w_p, feats_p,
+        items, src_p, dst_p, w_p, feats_p,
         block_e=block_e, block_v=block_v, block_dst=block_dst,
         block_d=block_d, num_dst_padded=num_dst_padded, interpret=interpret,
     )
@@ -191,14 +292,16 @@ def edge_block_spmm(
 ) -> jax.Array:
     """Returns [num_dst, D] f32: segment-sum of w-scaled source rows.
 
-    Block sizes default to ``auto_blocks`` for the operand shapes.  Each
-    operand is padded only when its shape is not already a block
-    multiple — an aligned call performs no copies at all — and an empty
-    edge list short-circuits to zeros without launching the kernel.
+    The edge list is read on the host (concrete arrays only), sorted by
+    destination and padded there; the item tables are built from it.
+    Block sizes default to ``auto_blocks`` for the operand shapes.  The
+    features are padded only when their shape is not already a block
+    multiple, and an empty edge list short-circuits to zeros without
+    launching the kernel.
     """
     v_src, d = feats.shape
     e = src.shape[0]
-    if e == 0:
+    if e == 0 or num_dst == 0:
         return jnp.zeros((num_dst, d), jnp.float32)
 
     a_e, a_v, a_dst, a_d = auto_blocks(v_src, d, e, num_dst, interpret)
@@ -216,20 +319,22 @@ def edge_block_spmm(
         feats_p = jnp.zeros((vp, dp), feats.dtype).at[:v_src, :d].set(feats)
     else:
         feats_p = feats
-    src = src.astype(jnp.int32)
-    dst = dst.astype(jnp.int32)
-    w = w.astype(jnp.float32)
-    if ep != e:
-        src_p = jnp.full((ep, 1), -1, jnp.int32).at[:e, 0].set(src)
-        dst_p = jnp.full((ep, 1), -1, jnp.int32).at[:e, 0].set(dst)
-        w_p = jnp.zeros((ep, 1), jnp.float32).at[:e, 0].set(w)
-    else:
-        src_p = src.reshape(ep, 1)
-        dst_p = dst.reshape(ep, 1)
-        w_p = w.reshape(ep, 1)
+    dst_h = np.asarray(dst, np.int32)
+    order = np.argsort(dst_h, kind="stable")
+    src_p = np.full((ep, 1), -1, np.int32)
+    dst_p = np.full((ep, 1), -1, np.int32)
+    w_p = np.zeros((ep, 1), np.float32)
+    src_p[:e, 0] = np.asarray(src, np.int32)[order]
+    dst_p[:e, 0] = dst_h[order]
+    w_p[:e, 0] = np.asarray(w, np.float32)[order]
+    items = work_items(
+        dst_p[:e, 0], num_dst,
+        block_e=block_e, block_dst=block_dst, ep=ep, jp=jp_,
+    )
 
     out = edge_block_spmm_padded(
-        src_p, dst_p, w_p, feats_p,
+        jnp.asarray(items), jnp.asarray(src_p), jnp.asarray(dst_p),
+        jnp.asarray(w_p), feats_p,
         block_e=block_e, block_v=block_v, block_dst=block_dst,
         block_d=block_d, num_dst_padded=jp_, interpret=interpret,
     )

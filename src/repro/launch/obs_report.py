@@ -13,6 +13,10 @@ Loads a Chrome trace-event file exported by ``repro.obs.trace.Tracer``
   the reader / layer wall),
 * the dominant bottleneck category.
 
+With telemetry it also prints, per layer, the aggregation kernel's grid
+steps run against those a grid over every (destination tile, edge
+block) pair would run (``spmm_steps`` / ``spmm_dense_steps``).
+
 ``--check`` validates the trace-event schema (well-formed ``ph``/``ts``/
 ``tid`` fields, strictly nested B/E pairs per thread) and exits non-zero
 on violations — CI runs this against the bench-leg trace artifacts.
@@ -321,6 +325,27 @@ def _load_layer_metrics(path: str) -> list[dict]:
     return find(data) or []
 
 
+def spmm_step_ratios(layer_metrics: list[dict]) -> list[dict]:
+    """Per layer that ran the aggregation kernel: its grid steps, the
+    dense grid's, and their ratio."""
+    rows = []
+    for i, m in enumerate(layer_metrics):
+        dense = int(m.get("spmm_dense_steps", 0))
+        if dense:
+            steps = int(m.get("spmm_steps", 0))
+            rows.append({"layer": m.get("layer", i), "steps": steps,
+                         "dense_steps": dense, "ratio": steps / dense})
+    return rows
+
+
+def _print_spmm_steps(rows: list[dict], out=None) -> None:
+    p = lambda *a: print(*a, file=out)  # noqa: E731
+    p("\nedge_block_spmm grid steps (run / dense grid):")
+    for r in rows:
+        p(f"  layer {r['layer']}: {r['steps']:,} of {r['dense_steps']:,} "
+          f"({r['ratio']:.2%})")
+
+
 def _find_cache_counters(path: str) -> list[dict]:
     """Serving page-cache counter dicts from a telemetry/bench JSON: any
     ``serve.cache`` registry subtree (MetricsRegistry snapshot) or
@@ -396,6 +421,10 @@ def main(argv: list[str] | None = None) -> int:
             _print_cache_counters(cache_counters)
             report["serve_cache"] = cache_counters
         layer_metrics = _load_layer_metrics(args.telemetry)
+        spmm_steps = spmm_step_ratios(layer_metrics)
+        if spmm_steps:
+            _print_spmm_steps(spmm_steps)
+            report["spmm_steps"] = spmm_steps
         if not layer_metrics:
             print(f"\nwarning: no LayerMetrics found in {args.telemetry}; "
                   "skipping reconciliation", file=sys.stderr)

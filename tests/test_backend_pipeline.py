@@ -68,6 +68,10 @@ def _chunk(rng, n, d, m, num_dst):
         (33, 130, 257, 77),  # nothing a multiple of any block
         (1, 1, 1, 1),  # degenerate minimum
         (300, 24, 2000, 40),  # heavy fan-in (many edges per dst)
+        (64, 16, 1500, 1),  # one hub destination over several edge blocks
+        # ~690 segments in a 1024-row pow-2 bucket: 3 tiles hold
+        # segments, the last only padding; 12 edge blocks in 16
+        (200, 24, 3000, 700),
     ],
 )
 @pytest.mark.parametrize("kind", ["gcn", "sage", "gin"])
@@ -205,7 +209,9 @@ def test_spmm_sentinel_padding_edges_contribute_nothing():
     "v_src,num_dst,e,d",
     [(3, 5, 7, 2),  # everything smaller than any block
      (16, 16, 50, 200),  # d spans more than one interpret tile
-     (9, 1, 4, 1)],  # single destination / single feature
+     (9, 1, 4, 1),  # single destination / single feature
+     # 2 src tiles of 1024 rows; dst tile boundary inside an edge block
+     (2000, 300, 600, 8)],
 )
 def test_spmm_auto_blocks_small_and_ragged(v_src, num_dst, e, d):
     """No explicit block sizes: auto_blocks must pick valid tiles for
@@ -238,7 +244,7 @@ def test_spmm_auto_blocks_divide_padded_shapes():
 
 
 def test_spmm_aligned_call_matches_padded_path():
-    """Block-aligned operands take the zero-copy path and still match."""
+    """Block-aligned features skip the device-side pad and still match."""
     import jax.numpy as jnp
 
     from repro.kernels.edge_block_spmm import edge_block_spmm
@@ -252,6 +258,102 @@ def test_spmm_aligned_call_matches_padded_path():
                           block_dst=32, block_d=16, interpret=True)
     want = _spmm_ref(feats, src, dst, w, 32)
     np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
+
+
+def _sorted_dst(kind, rng, m, num_dst):
+    if kind == "uniform":
+        dst = rng.integers(0, num_dst, m)
+    elif kind == "hub":  # most edges into one destination
+        dst = np.where(rng.random(m) < 0.8, num_dst // 2,
+                       rng.integers(0, num_dst, m))
+    elif kind == "dense":  # every destination once, in order
+        dst = np.arange(m) % num_dst
+    else:  # "gaps": whole 32-row tiles with no edge
+        dst = rng.integers(0, max(1, num_dst // 64), m) * 64
+    return np.sort(dst)
+
+
+@pytest.mark.parametrize("kind", ["uniform", "hub", "dense", "gaps"])
+@pytest.mark.parametrize("m,num_dst", [(1, 1), (300, 40), (2000, 700)])
+def test_work_items_cover_each_edge_pair_once(kind, m, num_dst):
+    """The item tables list every (tile, block) pair that holds an edge
+    exactly once, a tile's items consecutively and no more than the
+    static bound; their length depends on the padded shapes alone."""
+    from repro.kernels.edge_block_spmm import max_work_items, work_items
+
+    be, bdst = 64, 32
+    rng = np.random.default_rng(m + num_dst)
+    dst = _sorted_dst(kind, rng, m, num_dst)
+    ep = -(-m // be) * be * 2  # a pow-2-style bucket with spare blocks
+    jp = -(-num_dst // bdst) * bdst * 2
+    bound = max_work_items(ep, jp, be, bdst)
+    items = work_items(dst, num_dst, block_e=be, block_dst=bdst, ep=ep,
+                       jp=jp)
+    assert items.dtype == np.int32 and len(items) == 1 + 3 * bound
+    n = int(items[0])
+    tile, block, first = (items[1 + k * bound:1 + (k + 1) * bound]
+                          for k in range(3))
+    assert 1 <= n <= bound
+    pairs = list(zip(tile[:n].tolist(), block[:n].tolist()))
+    assert len(set(pairs)) == n
+    held = {(int(t) // bdst, e // be) for e, t in enumerate(dst)}
+    assert held <= set(pairs)
+    # the extra items are one per tile with no edge, which zeroes it
+    n_tiles = -(-num_dst // bdst)
+    empty = set(range(n_tiles)) - {t for t, _ in held}
+    assert len(pairs) == len(held) + len(empty)
+    assert {t for t, _ in set(pairs) - held} == empty
+    # sorted by tile then block; first flags each tile's first item
+    assert pairs == sorted(pairs)
+    assert set(tile[:n].tolist()) == set(range(n_tiles))
+    starts = np.r_[True, tile[1:n] != tile[:n - 1]]
+    np.testing.assert_array_equal(first[:n], starts.astype(np.int32))
+    # padding repeats the last real item and starts no tile
+    assert (tile[n:] == tile[n - 1]).all()
+    assert (block[n:] == block[n - 1]).all()
+    assert not first[n:].any()
+    assert (block[:n] < ep // be).all()
+
+
+def test_aggregator_compiles_the_kernel_once_per_bucket():
+    """Chunks whose edges and segments land in one padded bucket, with
+    any distribution of destinations, run one compiled program; the
+    aggregator counts fewer steps than a dense grid would run."""
+    import jax
+
+    from repro.kernels.edge_block_spmm import _spmm_jit
+
+    # block sizes no other test uses, so this bucket compiles here:
+    # 289..576 edges pad to 8 blocks of 72, 81..160 segments to 4 tiles
+    # of 40, 112 rows are 2 src tiles of 56 and d=48 is 2 d tiles of 24
+    agg = PallasChunkAggregator(jax.devices()[0], interpret=True,
+                                block_e=72, block_v=56, block_dst=40,
+                                block_d=24)
+    rng = np.random.default_rng(5)
+    n, d = 112, 48
+    feats = rng.normal(size=(n, d)).astype(np.float32)
+    chunks = [
+        rng.integers(0, 160, 500),  # uniform, ~153 segments
+        np.r_[np.full(300, 7), np.arange(100) * 3 + 8],  # a hub
+        np.arange(300) % 150,  # two edges per destination
+        np.repeat(np.arange(90) * 1000, 5),  # sparse global ids
+        np.r_[np.full(200, 1), np.arange(90) + 10],  # hub over 3 blocks
+    ]
+    before = _spmm_jit._cache_size()
+    for dst in chunks:
+        dst = rng.permutation(dst).astype(np.int64)
+        m = len(dst)
+        src_local = rng.integers(0, n, m).astype(np.int64)
+        w = rng.uniform(-1, 1, m).astype(np.float32)
+        ref = chunk_aggregate_numpy(feats, src_local, dst, w)
+        got = agg(feats, src_local, dst, w)
+        np.testing.assert_array_equal(got[0], ref[0])
+        np.testing.assert_array_equal(got[2], ref[2])
+        np.testing.assert_allclose(got[1], ref[1], rtol=1e-4, atol=1e-5)
+    assert _spmm_jit._cache_size() - before == 1
+    # 5 chunks x 4 tiles x 8 blocks x 2 d tiles x 2 src tiles
+    assert agg.spmm_dense_steps == 5 * 4 * 8 * 2 * 2
+    assert 0 < agg.spmm_steps < agg.spmm_dense_steps
 
 
 # --------------------------------------------------------------------------

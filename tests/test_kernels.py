@@ -32,7 +32,13 @@ def rtol_for(dt):
 @pytest.mark.parametrize(
     "v_src,num_dst,e,d",
     [(64, 64, 200, 16), (300, 150, 1000, 32), (1100, 700, 4000, 130),
-     (50, 2000, 512, 64)],
+     (50, 2000, 512, 64),
+     # two hub destinations, each over several 128-edge blocks
+     (64, 2, 1000, 16),
+     # a single edge
+     (40, 1, 1, 8),
+     # 2 d tiles, 3 src tiles, dst tile boundaries inside edge blocks
+     (600, 260, 3000, 72)],
 )
 def test_spmm_shapes(v_src, num_dst, e, d):
     rng = np.random.default_rng(e)

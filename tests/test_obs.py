@@ -420,6 +420,7 @@ def test_round_trip_counters_reconcile_with_spans(tmp_path):
                  + m.d2h_seconds)
         assert parts <= m.aggregate_seconds
         assert m.evict_seconds <= m.deliver_seconds
+        assert 0 < m.spmm_steps <= m.spmm_dense_steps
     events = load_trace(res.trace_path)
     assert validate_trace(events) == []
     report = analyze(events)
@@ -431,6 +432,28 @@ def test_round_trip_counters_reconcile_with_spans(tmp_path):
     fields = ROUND_TRIP + ("aggregate_seconds",)
     problems = reconcile(report, [m.as_dict() for m in res.metrics])
     assert [p for p in problems if p.split(":")[0] in fields] == []
+
+
+def test_obs_report_prints_spmm_step_ratio(tmp_path, capsys):
+    """With telemetry, obs_report prints each layer's kernel grid steps
+    against the dense grid's, and carries them in its JSON."""
+    from repro.launch.obs_report import main
+
+    res = _run_pallas(tmp_path, trace=True)
+    telemetry = tmp_path / "telemetry.json"
+    telemetry.write_text(json.dumps(res.telemetry))
+    out_json = tmp_path / "report.json"
+    assert main([res.trace_path, "--telemetry", str(telemetry),
+                 "--json", str(out_json)]) == 0
+    printed = capsys.readouterr().out
+    rows = json.loads(out_json.read_text())["spmm_steps"]
+    assert [r["layer"] for r in rows] == [m.layer for m in res.metrics]
+    for r, m in zip(rows, res.metrics):
+        assert (r["steps"], r["dense_steps"]) == (
+            m.spmm_steps, m.spmm_dense_steps)
+        assert r["ratio"] == pytest.approx(m.spmm_steps / m.spmm_dense_steps)
+        assert (f"layer {m.layer}: {m.spmm_steps:,} of "
+                f"{m.spmm_dense_steps:,} ({r['ratio']:.2%})") in printed
 
 
 def test_tracing_changes_no_count_and_no_output(tmp_path):

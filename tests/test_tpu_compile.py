@@ -2,8 +2,9 @@
 
 The TPU compiler is installed with JAX; it compiles for a topology that
 is described, not attached.  These tests compile what the chip runs —
-the ``edge_block_spmm`` kernel at the padded shapes
-``PallasChunkAggregator`` produces with the blocks it picks on a TPU,
+the ``edge_block_spmm`` kernel, its item tables by scalar prefetch, at
+the padded shapes ``PallasChunkAggregator`` produces with the blocks it
+picks on a TPU,
 and the mesh exchange's ``all_to_all`` route over four chips — so a
 change the chip's compiler would refuse (unaligned tiles, too much VMEM,
 a collective that cannot be partitioned) fails here, not on the chip.
@@ -25,7 +26,11 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.broadcast import padded_dims
 from repro.dist.exchange import all_to_all_route
-from repro.kernels.edge_block_spmm import _spmm_jit, auto_blocks
+from repro.kernels.edge_block_spmm import (
+    _spmm_jit,
+    auto_blocks,
+    max_work_items,
+)
 
 
 @pytest.fixture(scope="module")
@@ -60,7 +65,10 @@ def test_spmm_kernel_compiles_for_v5e(topo, n, d, m, n_seg):
     def shape(s, dtype):
         return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
 
+    # the item tables arrive by scalar prefetch, padded to their bound
+    items = 1 + 3 * max_work_items(ep, jp, be, bdst)
     compiled = _spmm_jit.lower(
+        shape((items,), jnp.int32),
         shape((ep, 1), jnp.int32),
         shape((ep, 1), jnp.int32),
         shape((ep, 1), jnp.float32),
