@@ -46,8 +46,9 @@ def main(argv=None) -> int:
 
     bench = cells.load_benchmark()
     cell = cells.find_cell(args.workload, bench)
-    cfg = cells.load_config(cell["config"])
     mix = cells.load_traffic(cell["traffic"])
+    cfg = cells.sized_config(cells.load_config(cell["config"]), mix,
+                             cell["chips"])
     run.enable_compile_cache()
     import jax
 

@@ -1,9 +1,10 @@
 """Finds a cell's pieces by name: the cell in ``BENCHMARK.json``, its
-configuration under ``configs/``, its traffic mix under ``traffic/`` and
-each per-layer metric's reader under ``metrics/``.
+configuration under ``configs/``, its traffic mix under ``traffic/``, the
+module of its configuration's layer kind under ``kinds/`` and each
+per-layer metric's reader under ``metrics/``.
 
-A new configuration, mix or metric is a new file plus a new entry in
-``BENCHMARK.json``; nothing here names one.
+A new configuration, mix, layer kind or metric is a new file plus a new
+entry in ``BENCHMARK.json``; nothing here names one.
 """
 
 from __future__ import annotations
@@ -15,6 +16,7 @@ import os
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 CHECKOUT = os.path.dirname(BENCH_DIR)
 BENCHMARK_JSON = os.path.join(CHECKOUT, "BENCHMARK.json")
+KINDS_DIR = os.path.join(BENCH_DIR, "kinds")
 
 
 def _load_json(path: str, what: str) -> dict:
@@ -51,16 +53,36 @@ def load_traffic(name: str) -> dict:
     return mix
 
 
-def metric_reader(name: str):
-    """The ``read(record)`` function of ``metrics/<name>.py``."""
-    path = os.path.join(BENCH_DIR, "metrics", f"{name}.py")
+def sized_config(cfg: dict, mix: dict, chips: int) -> dict:
+    """The configuration as the cell runs it.  A mix with
+    ``"vertices_per_chip": true`` gives every chip the configuration's
+    ``num_vertices``, so the graph has ``chips`` times as many."""
+    if mix.get("vertices_per_chip"):
+        cfg = dict(cfg, num_vertices=cfg["num_vertices"] * chips)
+    return cfg
+
+
+def _load_module(path: str, module_name: str, what: str):
     if not os.path.exists(path):
-        raise FileNotFoundError(f"no reader for metric {name!r} at {path}")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
+        raise FileNotFoundError(f"no {what} at {path}")
+    spec = importlib.util.spec_from_file_location(module_name, path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def metric_reader(name: str):
+    """The ``read(record)`` function of ``metrics/<name>.py``."""
+    return _load_module(os.path.join(BENCH_DIR, "metrics", f"{name}.py"),
+                        f"bench_metric_{name}",
+                        f"reader for metric {name!r}").read
+
+
+def layer_kind(name: str):
+    """The module ``kinds/<name>.py`` of a GNN layer kind: its weights,
+    its layer equation for the reference and its work counts."""
+    return _load_module(os.path.join(KINDS_DIR, f"{name}.py"),
+                        f"bench_kind_{name}", f"GNN layer kind {name!r}")
 
 
 def cell_metrics(cell: dict, bench: dict, section: str) -> list[dict]:
@@ -81,6 +103,7 @@ def hot_bytes(cfg: dict, mix: dict) -> int:
     if "share_of_layer0_feature_bytes" in rule:
         return int(rule["share_of_layer0_feature_bytes"] * v * dims[0] * 4)
     if rule.get("widest_hot_state"):
-        widest = max(2 * d if cfg["kind"] == "sage" else d for d in dims[:-1])
+        kind = layer_kind(cfg["kind"])
+        widest = max(kind.hot_width(d) for d in dims[:-1])
         return v * widest * 4
     raise ValueError(f"traffic mix {mix['name']!r}: unknown hot_budget {rule}")

@@ -16,6 +16,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from bench import cells
+
 GRAPH_STREAM, FEATURE_STREAM, WEIGHT_STREAM = 1, 2, 3
 
 
@@ -81,16 +83,9 @@ def glorot(rng: np.random.Generator, shape) -> np.ndarray:
 
 
 def make_weights(kind: str, dims, seed: int) -> list[dict]:
-    """Per layer ``{"w": [fan_in, out], "b": [out]}``; SAGE's ``w`` takes
-    the self half and the neighbour half stacked (``fan_in = 2 * in``).
-    The bias is drawn too (small), so that it is not left out unseen."""
+    """Per layer the weights of ``kinds/<kind>.py``, drawn layer by layer
+    from one stream of the seed."""
     rng = rng_for(seed, WEIGHT_STREAM)
-    layers = []
-    for d_in, d_out in zip(dims[:-1], dims[1:]):
-        fan_in = 2 * d_in if kind == "sage" else d_in
-        if kind not in ("gcn", "sage"):
-            raise ValueError(f"unknown GNN kind {kind!r}")
-        w = glorot(rng, (fan_in, d_out))
-        b = (0.01 * rng.standard_normal(d_out)).astype(np.float32)
-        layers.append({"w": w, "b": b})
-    return layers
+    layer_weights = cells.layer_kind(kind).make_weights
+    return [layer_weights(rng, d_in, d_out)
+            for d_in, d_out in zip(dims[:-1], dims[1:])]

@@ -1,6 +1,6 @@
 """Model FLOP utilisation of a whole pass (%): the model operations of one
 pass (aggregation and update of every layer, ``bench/work.py``) over the
-traced pass's length times the chip's peak."""
+traced pass's length times the peak of all the cell's chips."""
 
 from bench import work
 
@@ -12,4 +12,5 @@ def read(record):
     cfg = record["config"]
     flops = work.pass_model_flops(cfg["kind"], record["num_vertices"],
                                   record["num_edges"], cfg["dims"])
-    return 100.0 * flops / ((w1 - w0) / 1e9 * record["peaks"]["flops_per_s"])
+    peak = record["chips"] * record["peaks"]["flops_per_s"]
+    return 100.0 * flops / ((w1 - w0) / 1e9 * peak)

@@ -2,25 +2,33 @@
 decides ``correct``.
 
 It follows the published layer equations and imports nothing of the
-program:
+program.  Each layer kind's equation is in ``kinds/<kind>.py``
+(``reference_graph`` and ``reference_layer``); this module runs them
+layer by layer, with ReLU after every layer but the last:
 
     GCN   h'_v = act(sum_{u->v} h_u / sqrt(d(u) d(v)) @ W + b)
           (self-loops are in the topology, d counts them)
     SAGE  h'_v = act([h_v ; mean_{u->v} h_u] @ W + b)
 
-with ``act`` = ReLU on every layer but the last, and ``d`` the in-degree.
+with ``d`` the in-degree.
 
 ``precision="exact"`` computes in float64: the reference.  ``"high"`` is
 the control: every product of two float32 numbers is taken as TPU's
 ``Precision.HIGH`` takes it, in three bfloat16 passes
 (``a_hi*b_hi + a_hi*b_lo + a_lo*b_hi``, each product exact in float32),
-summed in float32, and every intermediate is stored in float32.
+summed in float32, and every intermediate is stored in float32.  A kind
+takes every product through the ``product`` it is handed, so that the
+control covers its whole equation.
 """
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import scipy.sparse as sp
+
+from bench import cells
 
 PRECISIONS = ("exact", "high")
 
@@ -62,21 +70,31 @@ def in_degrees(indptr: np.ndarray, indices: np.ndarray) -> np.ndarray:
     return np.bincount(indices, minlength=len(indptr) - 1).astype(np.int64)
 
 
-def aggregation_matrix(kind: str, indptr, indices) -> sp.csr_matrix:
-    """``A[v, u]`` = the weight of edge ``u -> v`` for this layer kind."""
-    num_vertices = len(indptr) - 1
-    deg = np.maximum(in_degrees(indptr, indices), 1).astype(np.float64)
-    src = np.repeat(np.arange(num_vertices), np.diff(indptr))
-    dst = np.asarray(indices, dtype=np.int64)
-    if kind == "gcn":
-        w = 1.0 / np.sqrt(deg[src] * deg[dst])
-    elif kind == "sage":
-        w = 1.0 / deg[dst]
-    else:
-        raise ValueError(f"unknown GNN kind {kind!r}")
-    a = sp.csr_matrix((w, (dst, src)), shape=(num_vertices, num_vertices))
+def edges(indptr, indices) -> tuple[np.ndarray, np.ndarray]:
+    """``(src, dst)`` of every edge ``src -> dst``, as int64."""
+    src = np.repeat(np.arange(len(indptr) - 1), np.diff(indptr))
+    return src, np.asarray(indices, dtype=np.int64)
+
+
+def by_destination(weight, src, dst, num_vertices: int,
+                   dtype) -> sp.csr_matrix:
+    """``A[v, u]`` = the summed ``weight`` of the edges ``u -> v``, in
+    ``dtype``: row ``v`` of ``A @ h`` sums ``v``'s weighted messages."""
+    a = sp.csr_matrix((weight, (dst, src)),
+                      shape=(num_vertices, num_vertices))
     a.sum_duplicates()
+    a.data = a.data.astype(dtype)
     return a
+
+
+def linear(x: np.ndarray, layer: dict, product, dtype,
+           activation: bool) -> np.ndarray:
+    """``act(x @ W + b)`` with the layer's ``w`` and ``b``."""
+    h = np.asarray(product(x, layer["w"].astype(dtype)), dtype=dtype)
+    h += layer["b"].astype(dtype)
+    if activation:
+        np.maximum(h, 0, out=h)
+    return h
 
 
 def forward(kind: str, indptr, indices, feats: np.ndarray, weights,
@@ -85,20 +103,13 @@ def forward(kind: str, indptr, indices, feats: np.ndarray, weights,
     if precision not in PRECISIONS:
         raise ValueError(f"precision must be one of {PRECISIONS}")
     dtype = np.float64 if precision == "exact" else np.float32
-    a = aggregation_matrix(kind, indptr, indices)
-    if precision != "exact":
-        a.data = a.data.astype(np.float32)
+    layer_kind = cells.layer_kind(kind)
+    graph = layer_kind.reference_graph(indptr, indices, dtype)
+    product = functools.partial(_product, precision=precision)
     h = feats.astype(dtype)
     for i, layer in enumerate(weights):
-        agg = np.asarray(_product(a, h, precision), dtype=dtype)
-        x = np.concatenate([h, agg], axis=1) if kind == "sage" else agg
-        del agg
-        w = layer["w"].astype(dtype)
-        h = np.asarray(_product(x, w, precision), dtype=dtype)
-        h += layer["b"].astype(dtype)
-        del x
-        if i < len(weights) - 1:
-            np.maximum(h, 0, out=h)
+        h = layer_kind.reference_layer(graph, h, layer, product, dtype,
+                                       activation=i < len(weights) - 1)
     return h
 
 

@@ -6,9 +6,11 @@
 
 Set-up makes the cell's graph, features and weights from ``--seed``,
 builds the program's on-disk ``GraphStore`` in the mix's vertex order,
-opens an ``AtlasSession`` with the compiled Pallas backend and runs one
-whole warm-up pass, which compiles (or loads from the persistent cache)
-every kernel shape the window will use.  ``--trace 0`` then runs whole
+opens an ``AtlasSession`` with the compiled Pallas backend (a
+``DistSession`` with one shard per chip, where the mix names an
+exchange) and runs one whole warm-up pass, which compiles (or loads from
+the persistent cache) every kernel shape the window will use.
+``--trace 0`` then runs whole
 ``session.infer`` passes back to back until ``--seconds`` have passed and
 reports the end-to-end metrics; ``--trace 1`` runs one pass under the
 JAX profiler and the program's span tracer and reports the per-layer
@@ -51,7 +53,8 @@ TOP = 10
 
 class CompileCounter:
     """Counts XLA compile requests and the persistent-cache hits among
-    them through ``jax.monitoring``."""
+    them through ``jax.monitoring``; ``compiled`` is the requests that
+    the persistent cache did not serve."""
 
     def __init__(self) -> None:
         from jax import monitoring
@@ -68,6 +71,10 @@ class CompileCounter:
     def _on_event(self, event, **kwargs) -> None:
         if event == "/jax/compilation_cache/cache_hits":
             self.cache_hits += 1
+
+    @property
+    def compiled(self) -> int:
+        return self.requests - self.cache_hits
 
 
 def log(msg: str) -> None:
@@ -120,9 +127,15 @@ class Cell:
 
 
 def build(cfg: dict, mix: dict, seed: int, workdir: str, backend: str,
-          tracer=None) -> Cell:
-    """Inputs from the seed, the program's store, specs and session."""
+          tracer=None, chips: int = 1) -> Cell:
+    """Inputs from the seed, the program's store, specs and session.
+
+    Each layer's spec takes every weight of the layer as ``params`` and
+    any further ``GNNLayerSpec`` fields from the configuration's
+    ``"spec"``.  A mix with an ``"exchange"`` runs the sharded engine:
+    one shard per chip, thread workers, that exchange between them."""
     from repro.core.atlas import AtlasConfig
+    from repro.dist.session import DistSession
     from repro.graphs.csr import CSRGraph
     from repro.models.gnn import GNNLayerSpec
     from repro.session import AtlasSession
@@ -143,17 +156,23 @@ def build(cfg: dict, mix: dict, seed: int, workdir: str, backend: str,
     dims = cfg["dims"]
     specs = [
         GNNLayerSpec(kind=cfg["kind"], in_dim=d_in, out_dim=d_out,
-                     activation=i < len(dims) - 2,
-                     params={"w": w["w"], "b": w["b"]})
+                     activation=i < len(dims) - 2, params=dict(w),
+                     **cfg.get("spec", {}))
         for i, (d_in, d_out, w) in enumerate(zip(dims[:-1], dims[1:],
                                                  weights))
     ]
     config = AtlasConfig(backend=backend, chunk_bytes=mix["chunk_bytes"],
                          hot_bytes=cells.hot_bytes(cfg, mix),
                          eviction=mix["eviction"])
-    session = AtlasSession(store, config=config,
-                           workdir=os.path.join(workdir, "run"),
-                           trace=tracer if tracer is not None else False)
+    run_dir = os.path.join(workdir, "run")
+    trace = tracer if tracer is not None else False
+    if "exchange" in mix:
+        session = DistSession(store, shards=chips, config=config,
+                              workdir=run_dir, exchange=mix["exchange"],
+                              workers="thread", trace=trace)
+    else:
+        session = AtlasSession(store, config=config, workdir=run_dir,
+                               trace=trace)
     return Cell(cfg, mix, seed, indptr, indices, feats, weights, store,
                 specs, session)
 
@@ -196,12 +215,26 @@ def device_info(devices) -> dict:
             "count": len(devices), "memory_peak_bytes": peak}
 
 
+def layer_records(result) -> list[dict]:
+    """The pass's counters: one dict per layer, or, from the sharded
+    engine, its shard reports, one per shard and layer."""
+    if hasattr(result, "shard_reports"):
+        return [info for layer in sorted(result.shard_reports)
+                for info in result.shard_reports[layer]]
+    return [m.as_dict() for m in result.metrics]
+
+
 def layer_lines(result) -> None:
-    for m in result.metrics:
-        log(f"  layer {m.layer}: seconds={m.seconds:.3f} "
-            f"aggregate={m.aggregate_seconds:.3f} h2d={m.h2d_seconds:.3f} "
-            f"transform={m.transform_seconds:.3f} chunks={m.chunks} "
-            f"evictions={m.evictions} reloads={m.reloads}")
+    for m in layer_records(result):
+        where = f"layer {m['layer']}"
+        if "shard" in m:
+            where += f" shard {m['shard']}"
+        times = " ".join(f"{k.removesuffix('_seconds')}={m[k]:.3f}"
+                         for k in ("seconds", "aggregate_seconds",
+                                   "h2d_seconds", "transform_seconds")
+                         if k in m)
+        log(f"  {where}: {times} chunks={m['chunks']} "
+            f"evictions={m['evictions']} reloads={m['reloads']}")
 
 
 def span_record(tracer, offset_ns: int, w0: int, w1: int) -> list[dict]:
@@ -227,7 +260,8 @@ def start_profiler(profile_dir: str) -> None:
     jax.profiler.start_trace(profile_dir, profiler_options=options)
 
 
-def traced_pass(cell: Cell, tracer, profile_dir: str, peaks: dict):
+def traced_pass(cell: Cell, tracer, profile_dir: str, peaks: dict,
+                chips: int):
     """One pass under the profiler; returns the pass, its start on the
     wall clock and the record the per-layer metric readers take."""
     import jax
@@ -254,7 +288,8 @@ def traced_pass(cell: Cell, tracer, profile_dir: str, peaks: dict):
         "config": cell.cfg,
         "num_vertices": cell.num_vertices,
         "num_edges": cell.num_edges,
-        "layers": [m.as_dict() for m in result.metrics],
+        "chips": chips,
+        "layers": layer_records(result),
         "spans": spans,
         "trace": red,
         "peaks": peaks,
@@ -265,8 +300,10 @@ def traced_pass(cell: Cell, tracer, profile_dir: str, peaks: dict):
 def breakdown(record: dict) -> dict:
     red = record["trace"]
     ops = sorted(red["ops"].items(), key=lambda kv: -kv[1])[:TOP]
+    # the gaps are the first device's: label them by the thread that
+    # calls its kernels (the staging thread, or shard 0) and the main one
     threads = sorted({sp["thread"] for sp in record["spans"]
-                      if sp["cat"] == "aggregate"}) + ["MainThread"]
+                      if sp["cat"] == "aggregate"})[:1] + ["MainThread"]
     gaps = trace_reduce.label_gaps(red["gaps"], record["spans"], threads)
     top_gaps = sorted(gaps.items(), key=lambda kv: -kv[1])[:TOP]
     return {"device_ops": [[n, ns / 1e9] for n, ns in ops],
@@ -282,7 +319,8 @@ def run_cell(cell_def: dict, bench: dict, cfg: dict, mix: dict, seed: int,
     compiles = CompileCounter()
     with tempfile.TemporaryDirectory(prefix="atlas-bench-") as workdir:
         tracer = Tracer() if trace else None
-        cell = build(cfg, mix, seed, workdir, backend, tracer)
+        cell = build(cfg, mix, seed, workdir, backend, tracer,
+                     chips=len(devices))
         t0 = time.perf_counter()
         warm = cell.session.infer(cell.specs)
         log(f"warm-up pass: {time.perf_counter() - t0:.3f}s, "
@@ -290,13 +328,15 @@ def run_cell(cell_def: dict, bench: dict, cfg: dict, mix: dict, seed: int,
             f"from the persistent cache")
         layer_lines(warm)
         setup_s = time.perf_counter() - T_START
-        compiled_before = compiles.requests
+        requests_before = compiles.requests
+        compiled_before = compiles.compiled
 
         metrics: dict = {}
         extra: dict = {}
         if trace:
             result, wall0, record = traced_pass(
-                cell, tracer, os.path.join(workdir, "profile"), peaks)
+                cell, tracer, os.path.join(workdir, "profile"), peaks,
+                len(devices))
             layer_lines(result)
             for m in cells.cell_metrics(cell_def, bench, "per_layer"):
                 value = cells.metric_reader(m["name"])(record)
@@ -327,8 +367,10 @@ def run_cell(cell_def: dict, bench: dict, cfg: dict, mix: dict, seed: int,
             for m in cells.cell_metrics(cell_def, bench, "end_to_end"):
                 metrics[m["name"]] = {"value": values[m["name"]],
                                       "unit": m["unit"]}
-        window_compiles = compiles.requests - compiled_before
-        log(f"compiles inside the window: {window_compiles}")
+        window_compiles = compiles.compiled - compiled_before
+        log(f"compiles inside the window: {window_compiles} (compile "
+            f"requests {compiles.requests - requests_before}, the others "
+            f"served by the persistent cache)")
         if window_compiles:
             raise RuntimeError(
                 f"{window_compiles} compile(s) inside the measured window: "
@@ -368,8 +410,9 @@ def main(argv=None) -> int:
 
     bench = cells.load_benchmark()
     cell_def = cells.find_cell(args.workload, bench)
-    cfg = cells.load_config(cell_def["config"])
     mix = cells.load_traffic(cell_def["traffic"])
+    cfg = cells.sized_config(cells.load_config(cell_def["config"]), mix,
+                             cell_def["chips"])
     cache = enable_compile_cache()
     import jax
 

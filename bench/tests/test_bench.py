@@ -29,6 +29,7 @@ def test_every_piece_of_every_cell_is_found_by_name():
         cfg = cells.load_config(cell["config"])
         mix = cells.load_traffic(cell["traffic"])
         assert cells.hot_bytes(cfg, mix) > 0
+        assert callable(cells.layer_kind(cfg["kind"]).reference_layer)
         for section in ("end_to_end", "per_layer"):
             assert cells.cell_metrics(cell, bench, section)
     for m in bench["per_layer"]:
@@ -88,9 +89,11 @@ def test_work_functions_against_hand_counts():
 
 
 def _record(layers, ops, window_ns=(0, 10**9)):
+    for i, m in enumerate(layers):
+        m.setdefault("layer", i)
     return {
         "config": {"kind": "sage", "dims": [128, 256, 172]},
-        "num_vertices": 1000, "num_edges": 5000,
+        "num_vertices": 1000, "num_edges": 5000, "chips": 1,
         "layers": layers, "peaks": {"flops_per_s": 197e12,
                                     "bytes_per_s": 819e9},
         "trace": {"window_ns": window_ns, "devices": ["/device:TPU:0"],
