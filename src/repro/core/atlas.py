@@ -137,9 +137,13 @@ class LayerMetrics:
     # grid over every (destination tile, edge block) pair would run
     spmm_steps: int = 0
     spmm_dense_steps: int = 0
+    # the same two counts for the max aggregation's edge_block_segmax
+    segmax_steps: int = 0
+    segmax_dense_steps: int = 0
     # the delivery thread's per-chunk work
     deliver_seconds: float = 0.0  # shield set -> both _deliver -> cleared
     evict_seconds: float = 0.0  # cold-store eviction and reload
+    max_merge_seconds: float = 0.0  # the max combine into the hot store
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -354,7 +358,9 @@ class AtlasEngine:
                 threaded=cfg.threaded,
                 tracer=tr,
             )
-            aggregate = chunk_aggregate(cfg.backend)
+            aggregate = chunk_aggregate(
+                cfg.backend, projection=spec.source_projection
+            )
             if hasattr(aggregate, "tracer"):
                 aggregate.tracer = tr  # h2d spans inside jax/pallas backends
             it = iter(reader) if cfg.threaded else reader.read_serial()
@@ -389,7 +395,7 @@ class AtlasEngine:
             raise
         tr.end("open_layer", "setup")
         self_coef = self_coefficient(spec)
-        agg_col = spec.in_dim if spec.kind == "sage" else 0
+        agg_col = spec.neighbour_offset
 
         reload_fracs: list[float] = []
         chunks = 0
@@ -428,6 +434,7 @@ class AtlasEngine:
                             mm, orch, grad, u_dst, partial, counts,
                             col_offset=agg_col, shield=shield,
                             chunk_index=chunk.index,
+                            combine=spec.aggregation,
                         )
                     denom = len(u_dst) + (
                         chunk.num_vertices if spec.extra_self_message else 0
@@ -566,8 +573,11 @@ class AtlasEngine:
             d2h_seconds=pipe.d2h_seconds,
             spmm_steps=pipe.spmm_steps,
             spmm_dense_steps=pipe.spmm_dense_steps,
+            segmax_steps=pipe.segmax_steps,
+            segmax_dense_steps=pipe.segmax_dense_steps,
             deliver_seconds=deliver_seconds,
             evict_seconds=mm.evict_seconds,
+            max_merge_seconds=mm.max_merge_seconds,
         )
         if not own_scheduler:
             if barrier_handle is not None:
@@ -589,6 +599,7 @@ class AtlasEngine:
         col_offset: int,
         shield: np.ndarray,
         chunk_index: int,
+        combine: str = "sum",
     ) -> int:
         """Route one batch of pre-aggregated records to the hot store.
 
@@ -600,7 +611,9 @@ class AtlasEngine:
         policy then minimises).  ``shield`` is the chunk's soft eviction
         shield as a boolean mask over vertex ids.  Each sub-batch costs one
         activate, one accumulate, one orchestrator deliver, and one batched
-        policy update.  Returns the number of COLD->HOT reloads.
+        policy update.  ``combine`` is how the records merge into the
+        partials there (``MemoryManager.accumulate``).  Returns the number
+        of COLD->HOT reloads.
         """
         reloads_before = mm.reload_count
         cap = max(1, mm.num_slots)
@@ -609,7 +622,7 @@ class AtlasEngine:
             ps = partial[s : s + cap]
             cs = counts[s : s + cap]
             slots = mm.activate(vs, shield)
-            mm.accumulate(vs, ps, col_offset, slots=slots)
+            mm.accumulate(vs, ps, col_offset, slots=slots, combine=combine)
             done_mask, old_pending, new_pending = orch.deliver(vs, cs, chunk_index)
             live = ~done_mask
             if np.any(live):

@@ -4,16 +4,24 @@ For one streamed chunk of source vertices, construct all outgoing messages
 (m_{u->v} = w(u,v) * h_u) and pre-aggregate them *by destination* so the
 memory manager touches each destination slot exactly once per chunk.
 
+Each aggregator sums the weighted rows, unless it is given a layer's
+``projection`` ``(w_pool, b_pool)``: then it is GraphSAGE's pooling
+aggregator, which maps each of the chunk's rows to
+``ReLU(h @ w_pool + b_pool)`` and takes the elementwise max per
+destination (no edge weights).
+
 Three interchangeable backends, selected by ``AtlasConfig.backend``:
 
-  * numpy  — sort-by-destination + ``np.add.reduceat`` (host fallback;
-             default on this CPU-only container),
-  * jax    — gather/scale/``segment_sum`` jit; the semantics twin of the
-             Pallas kernel and the reference it is atol-tested against,
-  * pallas — the ``edge_block_spmm`` one-hot MXU kernel (kernels/),
-             compiled for the TPU.  It refuses to run on a host whose
-             device is not a TPU; ``pallas-interpret`` runs the same kernel
-             body in interpret mode on any host (CI, equivalence tests).
+  * numpy  — sort-by-destination + ``np.add.reduceat`` /
+             ``np.maximum.reduceat`` (host fallback; the reference),
+  * jax    — gather/scale/``segment_sum`` (``segment_max``) jit; the
+             semantics twin of the Pallas kernels,
+  * pallas — the ``edge_block_spmm`` one-hot MXU kernel, or for the max
+             the projection and then ``edge_block_segmax`` in one jitted
+             call (kernels/), compiled for the TPU.  It refuses to run on
+             a host whose device is not a TPU; ``pallas-interpret`` runs
+             the same kernel bodies in interpret mode on any host (CI,
+             equivalence tests).
 
 All backends share one contract::
 
@@ -36,11 +44,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.edge_block_segmax import _segmax_call
 from repro.kernels.edge_block_spmm import (
     auto_blocks,
     edge_block_spmm_padded,
     work_items,
 )
+from repro.models.gnn import pool_projection
 from repro.obs.trace import NULL_TRACER
 
 
@@ -48,10 +58,12 @@ def chunk_aggregate_numpy(
     feats: np.ndarray,  # [n, d] chunk features (source rows)
     src_local: np.ndarray,  # [m] edge sources, chunk-local indices
     dst: np.ndarray,  # [m] edge destinations, global ids
-    weights: np.ndarray,  # [m] per-edge scalars
+    weights: np.ndarray | None,  # [m] per-edge scalars (sum only)
+    projection=None,  # (w_pool, b_pool): the max of projected rows
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Returns (unique_dst, partial_sums[, counts]): one row per distinct
-    destination touched by this chunk."""
+    """Returns (unique_dst, partials[, counts]): one row per distinct
+    destination touched by this chunk — the sum of its weighted rows, or
+    the max of its projected rows."""
     if len(dst) == 0:
         return (
             np.empty(0, dtype=np.int64),
@@ -60,12 +72,17 @@ def chunk_aggregate_numpy(
         )
     order = np.argsort(dst, kind="stable")
     sdst = dst[order]
-    msgs = feats[src_local[order]].astype(np.float32)
-    msgs *= weights[order][:, None]
     # segment boundaries over the destination-sorted edge list
     starts = np.nonzero(np.r_[True, sdst[1:] != sdst[:-1]])[0]
     unique_dst = sdst[starts].astype(np.int64)
-    partial = np.add.reduceat(msgs, starts, axis=0)
+    if projection is not None:
+        rows = pool_projection(projection, feats.astype(np.float32))
+        msgs = rows[src_local[order]]
+        partial = np.maximum.reduceat(msgs, starts, axis=0)
+    else:
+        msgs = feats[src_local[order]].astype(np.float32)
+        msgs *= weights[order][:, None]
+        partial = np.add.reduceat(msgs, starts, axis=0)
     counts = np.diff(np.r_[starts, len(sdst)]).astype(np.int64)
     return unique_dst, partial, counts
 
@@ -74,6 +91,37 @@ def chunk_aggregate_numpy(
 def _segment_messages(feats, src_local, seg_ids, weights, num_segments):
     msgs = feats[src_local] * weights[:, None]
     return jax.ops.segment_sum(msgs, seg_ids, num_segments=num_segments)
+
+
+def _pool_rows(feats, w_pool, b_pool):
+    """The pooling aggregator's messages ``ReLU(h @ w_pool + b_pool)``
+    on the device, at full float32 precision."""
+    rows = jnp.dot(feats, w_pool, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+    return jnp.maximum(rows + b_pool, 0.0)
+
+
+@functools.partial(jax.jit, static_argnames=("num_segments",))
+def _segment_pool_max(feats, src_local, seg_ids, w_pool, b_pool,
+                      num_segments):
+    msgs = _pool_rows(feats, w_pool, b_pool)[src_local]
+    return jax.ops.segment_max(msgs, seg_ids, num_segments=num_segments)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("block_e", "block_v", "block_dst", "block_d",
+                     "num_dst_padded", "interpret"),
+)
+def _pool_segmax(items, src_p, dst_p, feats_p, w_pool, b_pool, *,
+                 block_e, block_v, block_dst, block_d, num_dst_padded,
+                 interpret):
+    """The projection, then ``edge_block_segmax``, in one device call."""
+    return _segmax_call(
+        items, src_p, dst_p, _pool_rows(feats_p, w_pool, b_pool),
+        block_e=block_e, block_v=block_v, block_dst=block_dst,
+        block_d=block_d, num_dst_padded=num_dst_padded, interpret=interpret,
+    )
 
 
 def chunk_aggregate_jax(
@@ -121,19 +169,22 @@ def chunk_aggregate_jax(
 class JaxChunkAggregator:
     """``chunk_aggregate_jax`` semantics with the round trip timed.
 
-    Same outputs as the bare function (shares ``_segment_messages``).
-    Each step of the per-chunk round trip is timed into its own counter
-    and traced under its own span: the host dictionary and padding
-    (``dedup_seconds``), the device_put of the four operands onto
-    ``device`` (``h2d_seconds``), the kernel call and the wait for it
-    (``kernel_wait_seconds``) and the copy of the output back to the host
-    (``d2h_seconds``).
+    Same outputs as the bare function (shares ``_segment_messages``);
+    given a ``projection`` it projects the rows and takes
+    ``segment_max`` instead.  Each step of the per-chunk round trip is
+    timed into its own counter and traced under its own span: the host
+    dictionary and padding (``dedup_seconds``), the device_put of the
+    operands onto ``device`` (``h2d_seconds``), the kernel call and the
+    wait for it (``kernel_wait_seconds``) and the copy of the output
+    back to the host (``d2h_seconds``).
     """
 
     backend = "jax"
 
-    def __init__(self, device: jax.Device) -> None:
+    def __init__(self, device: jax.Device, projection=None) -> None:
         self.device = device
+        self._projection = projection
+        self._pool = None  # the projection on the device, once put
         self.dedup_seconds = 0.0
         self.h2d_seconds = 0.0
         self.kernel_wait_seconds = 0.0
@@ -148,6 +199,7 @@ class JaxChunkAggregator:
                 np.empty(0, dtype=np.int64),
             )
         tr = self.tracer
+        pool = self._projection is not None
         with tr.span("dedup", "dedup"):
             t0 = time.perf_counter()
             unique_dst, seg_ids, counts = np.unique(
@@ -160,23 +212,30 @@ class JaxChunkAggregator:
             src_p[:m] = src_local
             seg_p = np.full(pad, n_seg, dtype=np.int32)
             seg_p[:m] = seg_ids
-            w_p = np.zeros(pad, dtype=np.float32)
-            w_p[:m] = weights
+            operands = [np.ascontiguousarray(feats, np.float32), src_p, seg_p]
+            if not pool:
+                w_p = np.zeros(pad, dtype=np.float32)
+                w_p[:m] = weights
+                operands.append(w_p)
             self.dedup_seconds += time.perf_counter() - t0
         with tr.span("h2d", "h2d"):
             t0 = time.perf_counter()
-            feats_d, src_d, seg_d, w_d = (
-                jax.device_put(x, self.device)
-                for x in (np.ascontiguousarray(feats, np.float32),
-                          src_p, seg_p, w_p)
-            )
-            jax.block_until_ready((feats_d, src_d, seg_d, w_d))
+            operands = [jax.device_put(x, self.device) for x in operands]
+            if pool and self._pool is None:
+                self._pool = [
+                    jax.device_put(np.asarray(x, np.float32), self.device)
+                    for x in self._projection
+                ]
+            jax.block_until_ready(operands)
             self.h2d_seconds += time.perf_counter() - t0
         with tr.span("kernel_wait", "kernel"):
             t0 = time.perf_counter()
-            out = _segment_messages(
-                feats_d, src_d, seg_d, w_d, num_segments=n_seg + 1
-            )
+            if pool:
+                out = _segment_pool_max(
+                    *operands, *self._pool, num_segments=n_seg + 1
+                )
+            else:
+                out = _segment_messages(*operands, num_segments=n_seg + 1)
             out.block_until_ready()
             self.kernel_wait_seconds += time.perf_counter() - t0
         with tr.span("d2h", "d2h"):
@@ -215,16 +274,18 @@ def padded_dims(
 
 
 class PallasChunkAggregator:
-    """Pallas ``edge_block_spmm`` as a chunk_aggregate backend.
+    """Pallas ``edge_block_spmm``, or given a ``projection`` the
+    projection and ``edge_block_segmax``, as a chunk_aggregate backend.
 
     Host side builds the chunk's destination dictionary from one stable
     sort of the destinations, so the kernel runs over *dense* segment ids
     (``num_dst = n_seg``) instead of global vertex ids — the out tile
     count tracks the chunk's fan-out, not |V| — and gets its edges in
     segment order, which its grid over (destination tile, edge block)
-    items needs (``work_items``).  ``spmm_steps`` counts the grid steps
-    run (padding items excluded), ``spmm_dense_steps`` the steps a grid
-    over every such pair would run at the same padded shapes.
+    items needs (``work_items``).  ``spmm_steps`` (``segmax_steps`` under
+    the max) counts the grid steps run (padding items excluded),
+    ``spmm_dense_steps`` (``segmax_dense_steps``) the steps a grid over
+    every such pair would run at the same padded shapes.
 
     Chunk-to-chunk reuse: operand padding happens in host scratch buffers
     keyed by padded shape (allocated once per bucket, refilled per call;
@@ -250,6 +311,7 @@ class PallasChunkAggregator:
         block_v: int | None = None,
         block_dst: int | None = None,
         block_d: int | None = None,
+        projection=None,
     ) -> None:
         if not interpret and device.platform != "tpu":
             raise RuntimeError(
@@ -261,6 +323,8 @@ class PallasChunkAggregator:
             )
         self.device = device
         self.interpret = bool(interpret)
+        self._projection = projection
+        self._pool = None  # the projection padded to (dp, dp), on device
         self._blocks = (
             (block_e, block_v, block_dst, block_d)
             if all((block_e, block_v, block_dst, block_d))
@@ -272,13 +336,16 @@ class PallasChunkAggregator:
         self.d2h_seconds = 0.0
         self.spmm_steps = 0
         self.spmm_dense_steps = 0
+        self.segmax_steps = 0
+        self.segmax_dense_steps = 0
         self.tracer = NULL_TRACER
         self._feat_scratch: dict[tuple[int, int], np.ndarray] = {}
         self._edge_scratch: dict[int, tuple[np.ndarray, np.ndarray, np.ndarray]] = {}
 
     def _edges(self, ep: int, m: int, order, src_local, seg_ids, weights):
         """The edges in segment order (``order`` sorts them) in the
-        scratch buffers of bucket ``ep``, padding margins reset."""
+        scratch buffers of bucket ``ep``, padding margins reset; the
+        weights stay unfilled when there are none (the max)."""
         buf = self._edge_scratch.get(ep)
         if buf is None:
             buf = (
@@ -292,8 +359,9 @@ class PallasChunkAggregator:
         src_p[m:, 0] = -1
         dst_p[:m, 0] = seg_ids
         dst_p[m:, 0] = -1
-        w_p[:m, 0] = weights[order]
-        w_p[m:, 0] = 0.0
+        if weights is not None:
+            w_p[:m, 0] = weights[order]
+            w_p[m:, 0] = 0.0
         return src_p, dst_p, w_p
 
     def _feats(self, vp: int, dp: int, feats: np.ndarray) -> np.ndarray:
@@ -309,6 +377,18 @@ class PallasChunkAggregator:
         buf[:n, :d] = feats
         return buf
 
+    def _padded_projection(self, dp: int) -> list[jax.Array]:
+        """``(w_pool, b_pool)`` zero-padded to the feature width ``dp`` of
+        the kernel's operands, on the device: padded columns project to
+        ``ReLU(0) = 0`` and are never read back."""
+        w_pool, b_pool = self._projection
+        d = w_pool.shape[0]
+        w = np.zeros((dp, dp), np.float32)
+        w[:d, :d] = w_pool
+        b = np.zeros(dp, np.float32)
+        b[:d] = b_pool
+        return [jax.device_put(x, self.device) for x in (w, b)]
+
     def __call__(self, feats, src_local, dst, weights):
         if len(dst) == 0:
             return (
@@ -317,6 +397,7 @@ class PallasChunkAggregator:
                 np.empty(0, dtype=np.int64),
             )
         tr = self.tracer
+        pool = self._projection is not None
         with tr.span("dedup", "dedup"):
             t0 = time.perf_counter()
             # what np.unique does inside, keeping the sort: segment ids
@@ -346,26 +427,38 @@ class PallasChunkAggregator:
                 seg_ids, n_seg, block_e=be, block_dst=bdst, ep=ep, jp=jp
             )
             per_item = (dp // bd) * (vp // bv)
-            self.spmm_steps += int(items[0]) * per_item
-            self.spmm_dense_steps += (jp // bdst) * (ep // be) * per_item
+            steps = int(items[0]) * per_item
+            dense_steps = (jp // bdst) * (ep // be) * per_item
+            if pool:
+                self.segmax_steps += steps
+                self.segmax_dense_steps += dense_steps
+                operands = (items, src_p, dst_p, feats_p)
+            else:
+                self.spmm_steps += steps
+                self.spmm_dense_steps += dense_steps
+                operands = (items, src_p, dst_p, w_p, feats_p)
             self.dedup_seconds += time.perf_counter() - t0
 
         with tr.span("h2d", "h2d"):
             t0 = time.perf_counter()
             operands = tuple(
-                jax.device_put(x, self.device)
-                for x in (items, src_p, dst_p, w_p, feats_p)
+                jax.device_put(x, self.device) for x in operands
             )
+            if pool and self._pool is None:
+                self._pool = self._padded_projection(dp)
             jax.block_until_ready(operands)
             self.h2d_seconds += time.perf_counter() - t0
 
         with tr.span("kernel_wait", "kernel"):
             t0 = time.perf_counter()
-            out = edge_block_spmm_padded(
-                *operands,
+            blocks = dict(
                 block_e=be, block_v=bv, block_dst=bdst, block_d=bd,
                 num_dst_padded=jp, interpret=self.interpret,
             )
+            if pool:
+                out = _pool_segmax(*operands, *self._pool, **blocks)
+            else:
+                out = edge_block_spmm_padded(*operands, **blocks)
             out.block_until_ready()
             self.kernel_wait_seconds += time.perf_counter() - t0
 
@@ -385,7 +478,11 @@ class PallasChunkAggregator:
         )
 
 
-def chunk_aggregate(backend: str = "numpy", device_index: int = 0):
+def chunk_aggregate(
+    backend: str = "numpy",
+    device_index: int = 0,
+    projection=None,
+):
     """Resolve a backend name to a callable with the shared contract.
 
     ``numpy`` is a plain function and touches no JAX device.  The device
@@ -395,14 +492,20 @@ def chunk_aggregate(backend: str = "numpy", device_index: int = 0):
     ``s`` of a sharded run passes ``s``, so each shard's kernels run on
     its own chip.  ``pallas`` is the compiled kernel and raises off-TPU;
     ``pallas-interpret`` runs it in interpret mode on any host, which is
-    what CI and the equivalence tests use.
+    what CI and the equivalence tests use.  A layer's ``projection``
+    ``(w_pool, b_pool)`` (``GNNLayerSpec.source_projection``) makes it the
+    pooling aggregator's max.
     """
     if backend == "numpy":
-        return chunk_aggregate_numpy
+        if projection is None:
+            return chunk_aggregate_numpy
+        return functools.partial(chunk_aggregate_numpy, projection=projection)
     if backend not in ("jax", "pallas", "pallas-interpret"):
         raise ValueError(f"unknown broadcast backend {backend!r}")
     devices = jax.devices()
     device = devices[device_index % len(devices)]
     if backend == "jax":
-        return JaxChunkAggregator(device)
-    return PallasChunkAggregator(device, interpret=backend == "pallas-interpret")
+        return JaxChunkAggregator(device, projection)
+    return PallasChunkAggregator(
+        device, interpret=backend == "pallas-interpret", projection=projection,
+    )

@@ -22,6 +22,7 @@ import numpy as np
 from repro.graphs.csr import CSRGraph, csr_to_csc, degrees_from_csr
 from repro.models.gnn import (
     GNNLayerSpec,
+    aggregate_neighbours,
     edge_weights,
     layer_update,
     self_coefficient,
@@ -96,9 +97,8 @@ def layerwise_gather(
             stats.block_reads += n
             stats.rows_requested += len(need)
             w = edge_weights(spec.kind, src, dst, in_deg)
-            agg = np.zeros((e - s, spec.in_dim), dtype=np.float32)
-            np.add.at(agg, dst - s, h[src] * w[:, None])
-            if spec.kind == "sage":
+            agg = aggregate_neighbours(spec, h[src], dst - s, e - s, w)
+            if spec.concat_self:
                 agg = np.concatenate(
                     [h[s:e] * self_coefficient(spec), agg], axis=1
                 )
@@ -157,12 +157,15 @@ def vertexwise_gather(
             src, counts = in_neighbors(tgt)
             dstrep = np.repeat(tgt, counts)
             w = edge_weights(spec.kind, src, dstrep, in_deg)
-            agg = np.zeros((len(tgt), spec.in_dim), dtype=np.float32)
             src_rows = hcur[[pos[int(x)] for x in src]] if len(src) else np.empty((0, spec.in_dim), np.float32)
             tgt_index = {int(x): i for i, x in enumerate(tgt)}
-            np.add.at(agg, [tgt_index[int(x)] for x in dstrep], src_rows * w[:, None])
+            agg = aggregate_neighbours(
+                spec, src_rows,
+                np.asarray([tgt_index[int(x)] for x in dstrep], np.int64),
+                len(tgt), w,
+            )
             self_rows = hcur[[pos[int(x)] for x in tgt]]
-            if spec.kind == "sage":
+            if spec.concat_self:
                 agg = np.concatenate([self_rows * self_coefficient(spec), agg], axis=1)
             elif spec.kind == "gin":
                 agg = agg + self_rows * self_coefficient(spec)

@@ -15,6 +15,13 @@ boolean mask from the engine (no per-chunk Python sets).
 
 Traffic with the cold store is traced (``evict`` and ``reload`` spans,
 category ``cold``) and timed together into ``evict_seconds``.
+
+Partials merge by the layer's combine: ``+=`` for a sum, and for the
+pooling aggregator's neighbour columns an elementwise max (traced as
+``max_merge``, category ``merge``, timed into ``max_merge_seconds``).
+A fresh slot is zero-filled, which is the max's identity only because
+every message of that aggregator is ``>= 0`` (a ReLU output).  Eviction
+and reload copy whole rows, whichever the combine.
 """
 
 from __future__ import annotations
@@ -65,6 +72,7 @@ class MemoryManager:
         self.reload_count = 0
         self.peak_occupancy = 0
         self.evict_seconds = 0.0  # cold.put + eviction bookkeeping + reload
+        self.max_merge_seconds = 0.0  # accumulate(..., combine="max")
 
     # ---------------------------------------------------------- occupancy
     @property
@@ -173,22 +181,32 @@ class MemoryManager:
         partial: np.ndarray,
         col_offset: int = 0,
         slots: np.ndarray | None = None,
+        combine: str = "sum",
     ) -> None:
-        """hot[slot(v), off:off+w] += partial_v for unique vertices (all HOT).
+        """hot[slot(v), off:off+w] += partial_v for unique vertices (all HOT),
+        or under ``combine="max"`` the elementwise max of the two.
 
         ``col_offset`` supports SAGE's concat layout: self features occupy
         columns [0, d), neighbor aggregates [d, 2d) (paper §4.3).  ``slots``
         may carry the assignment just returned by ``activate`` to skip the
-        re-lookup.
+        re-lookup.  The max starts from the zero fill of a fresh slot, so
+        its partials must be ``>= 0``.
         """
         if slots is None:
             slots = self.slot_of[vertices]
         if np.any(slots < 0):
             raise RuntimeError("accumulate() on vertex without a hot slot")
-        width = partial.shape[1]
-        self.hot[slots, col_offset : col_offset + width] += partial.astype(
-            self.dtype, copy=False
-        )
+        cols = slice(col_offset, col_offset + partial.shape[1])
+        partial = partial.astype(self.dtype, copy=False)
+        if combine == "sum":
+            self.hot[slots, cols] += partial
+        elif combine == "max":
+            with self.tracer.span("max_merge", "merge"):
+                t0 = time.perf_counter()
+                self.hot[slots, cols] = np.maximum(self.hot[slots, cols], partial)
+                self.max_merge_seconds += time.perf_counter() - t0
+        else:
+            raise ValueError(f"unknown combine {combine!r}")
 
     def update_policy_scores(
         self, vertices: np.ndarray, old_pending: np.ndarray, new_pending: np.ndarray

@@ -80,6 +80,16 @@ class _AggregatorCounters:
         pair would have run at the same padded shapes."""
         return self._counter("spmm_dense_steps", 0)
 
+    @property
+    def segmax_steps(self) -> int:
+        """``spmm_steps`` of the max aggregation's kernel."""
+        return self._counter("segmax_steps", 0)
+
+    @property
+    def segmax_dense_steps(self) -> int:
+        """``spmm_dense_steps`` of the max aggregation's kernel."""
+        return self._counter("segmax_dense_steps", 0)
+
 
 def _next_chunk(chunks: Iterator, tracer):
     """The reader's next chunk (None when it is exhausted), the wait for

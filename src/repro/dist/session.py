@@ -47,7 +47,7 @@ import time
 from repro.core.atlas import AtlasConfig
 from repro.dist.exchange import ExchangeAborted, LocalExchange, make_exchange
 from repro.dist.partition import ShardPlan
-from repro.dist.worker import run_shard_layer
+from repro.dist.worker import check_layer_kinds, run_shard_layer
 from repro.graphs.csr import degrees_from_csr
 from repro.models.gnn import GNNLayerSpec
 from repro.obs.trace import as_tracer, merge_trace_files
@@ -341,7 +341,9 @@ class DistSession:
         shard count, same store identity).  ``fault`` is a test hook —
         ``fault(shard, layer, phase)`` called from thread workers at
         stream/post checkpoints; raise from it to simulate a worker
-        death."""
+        death.  A layer kind that the shards cannot combine (a max) is
+        refused before any work."""
+        check_layer_kinds(specs)
         store = self.store
         os.makedirs(self.workdir, exist_ok=True)
         manifest_path = self.run_manifest_path

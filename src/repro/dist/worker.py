@@ -72,6 +72,20 @@ def shard_hot_slots(
     return max(16, total // max(1, num_shards))
 
 
+def check_layer_kinds(specs) -> None:
+    """Refuse a layer whose neighbours combine by anything but a sum: the
+    sender-side combine and the receive phase add remote partials
+    (``np.add.at``), which would turn a max into a sum."""
+    for spec in specs:
+        if spec.aggregation != "sum":
+            raise ValueError(
+                f"the sharded engine cannot run layer kind {spec.kind!r}: "
+                f"its neighbours combine by {spec.aggregation}, and remote "
+                "partials are summed across shards; run it with "
+                "AtlasSession"
+            )
+
+
 def _merge_by_destination(
     dst_parts: list[np.ndarray],
     row_parts: list[np.ndarray],
@@ -118,6 +132,7 @@ def run_shard_layer(
     and ``'post'`` (between post and collect) and may raise to simulate
     a mid-layer worker death.
     """
+    check_layer_kinds([spec])
     cfg = config or AtlasConfig()
     tr = as_tracer(tracer if tracer is not None else cfg.trace)
     t0 = time.perf_counter()
@@ -213,7 +228,7 @@ def run_shard_layer(
         aggregate.tracer = tr
 
     self_coef = self_coefficient(spec)
-    agg_col = spec.in_dim if spec.kind == "sage" else 0
+    agg_col = spec.neighbour_offset
     shield = np.zeros(num_vertices, dtype=bool)
     # outgoing per-peer accumulators: lists of per-chunk (dst, rows, cnt)
     out_dst = [[] for _ in range(num_shards)]
@@ -382,4 +397,4 @@ def run_shard_layer(
     return layer_spills, info
 
 
-__all__ = ["run_shard_layer", "shard_hot_slots"]
+__all__ = ["check_layer_kinds", "run_shard_layer", "shard_hot_slots"]

@@ -30,7 +30,8 @@ from repro.util.compile_cache import enable_compile_cache
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--model", default="gcn", choices=["gcn", "sage", "gin"])
+    ap.add_argument("--model", default="gcn",
+                    choices=["gcn", "sage", "sagepool", "gin"])
     ap.add_argument("--vertices", type=int, default=50_000)
     ap.add_argument("--degree", type=int, default=12)
     ap.add_argument("--dim", type=int, default=64)

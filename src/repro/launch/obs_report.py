@@ -15,7 +15,8 @@ Loads a Chrome trace-event file exported by ``repro.obs.trace.Tracer``
 
 With telemetry it also prints, per layer, the aggregation kernel's grid
 steps run against those a grid over every (destination tile, edge
-block) pair would run (``spmm_steps`` / ``spmm_dense_steps``).
+block) pair would run (``spmm_steps`` / ``spmm_dense_steps``, and
+``segmax_steps`` / ``segmax_dense_steps`` for the max's kernel).
 
 ``--check`` validates the trace-event schema (well-formed ``ph``/``ts``/
 ``tid`` fields, strictly nested B/E pairs per thread) and exits non-zero
@@ -58,6 +59,7 @@ RECONCILE = {
     "d2h_seconds": ("d2h",),
     "deliver_seconds": ("deliver", "cold"),
     "evict_seconds": ("cold",),
+    "max_merge_seconds": ("merge",),
     "pipeline_stall_seconds": ("stall",),
     "transform_seconds": ("transform",),
     "barrier_seconds": ("barrier", "fsync"),
@@ -325,22 +327,27 @@ def _load_layer_metrics(path: str) -> list[dict]:
     return find(data) or []
 
 
-def spmm_step_ratios(layer_metrics: list[dict]) -> list[dict]:
-    """Per layer that ran the aggregation kernel: its grid steps, the
-    dense grid's, and their ratio."""
+# the aggregation kernels whose grid steps LayerMetrics counts, by the
+# prefix of their two counters
+STEP_KERNELS = ("spmm", "segmax")
+
+
+def step_ratios(layer_metrics: list[dict], kernel: str = "spmm") -> list[dict]:
+    """Per layer that ran the aggregation kernel ``edge_block_<kernel>``:
+    its grid steps, the dense grid's, and their ratio."""
     rows = []
     for i, m in enumerate(layer_metrics):
-        dense = int(m.get("spmm_dense_steps", 0))
+        dense = int(m.get(f"{kernel}_dense_steps", 0))
         if dense:
-            steps = int(m.get("spmm_steps", 0))
+            steps = int(m.get(f"{kernel}_steps", 0))
             rows.append({"layer": m.get("layer", i), "steps": steps,
                          "dense_steps": dense, "ratio": steps / dense})
     return rows
 
 
-def _print_spmm_steps(rows: list[dict], out=None) -> None:
+def _print_steps(rows: list[dict], kernel: str, out=None) -> None:
     p = lambda *a: print(*a, file=out)  # noqa: E731
-    p("\nedge_block_spmm grid steps (run / dense grid):")
+    p(f"\nedge_block_{kernel} grid steps (run / dense grid):")
     for r in rows:
         p(f"  layer {r['layer']}: {r['steps']:,} of {r['dense_steps']:,} "
           f"({r['ratio']:.2%})")
@@ -421,10 +428,11 @@ def main(argv: list[str] | None = None) -> int:
             _print_cache_counters(cache_counters)
             report["serve_cache"] = cache_counters
         layer_metrics = _load_layer_metrics(args.telemetry)
-        spmm_steps = spmm_step_ratios(layer_metrics)
-        if spmm_steps:
-            _print_spmm_steps(spmm_steps)
-            report["spmm_steps"] = spmm_steps
+        for kernel in STEP_KERNELS:
+            rows = step_ratios(layer_metrics, kernel)
+            if rows:
+                _print_steps(rows, kernel)
+                report[f"{kernel}_steps"] = rows
         if not layer_metrics:
             print(f"\nwarning: no LayerMetrics found in {args.telemetry}; "
                   "skipping reconciliation", file=sys.stderr)

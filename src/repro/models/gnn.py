@@ -1,22 +1,26 @@
 """Message-passing GNN layer definitions + in-memory reference oracle.
 
 The three models evaluated in the paper (§4.1): GraphConv/GCN [Kipf &
-Welling], SAGEConv (mean) [Hamilton et al.] and GINConv [Xu et al.].
-Each layer is described by a ``GNNLayerSpec`` that the broadcast engine,
-the gather baselines, and the dense oracle all consume, so semantic
-equivalence is checked against one single definition:
+Welling], SAGEConv (mean) [Hamilton et al.] and GINConv [Xu et al.],
+and GraphSAGE's pooling aggregator [Hamilton et al., §3.3].  Each layer
+is described by a ``GNNLayerSpec`` that the broadcast engine, the gather
+baselines, and the dense oracle all consume, so semantic equivalence is
+checked against one single definition:
 
-  GCN   m_{u->v} = h_u / sqrt(d_in(u) d_in(v))   (self-loops in topology)
-        h'_v = act(W @ Σ m + b)
-  SAGE  m_{u->v} = h_u / d_in(v)                 (mean over in-neighbors)
-        h'_v = act(W @ [h_v ; Σ m] + b)          (self-concat)
-  GIN   m_{u->v} = h_u
-        h'_v = MLP((1+eps) h_v + Σ m)            (2-layer MLP)
+  GCN       m_{u->v} = h_u / sqrt(d_in(u) d_in(v))  (self-loops in topology)
+            h'_v = act(W @ Σ m + b)
+  SAGE      m_{u->v} = h_u / d_in(v)                (mean over in-neighbors)
+            h'_v = act(W @ [h_v ; Σ m] + b)         (self-concat)
+  SAGEPOOL  m_{u->v} = ReLU(h_u @ W_pool + b_pool)
+            h'_v = act(W @ [h_v ; max m] + b)       (elementwise max; 0
+                                                     with no in-edges)
+  GIN       m_{u->v} = h_u
+            h'_v = MLP((1+eps) h_v + Σ m)           (2-layer MLP)
 
-The broadcast engine realises the self term for SAGE/GIN as an extra
-"self message" deposited when the vertex's own source chunk streams by
-(required message count = d_in + 1), and for GCN via self-loops — see
-DESIGN.md §2.
+The broadcast engine realises the self term for SAGE/SAGEPOOL/GIN as an
+extra "self message" deposited when the vertex's own source chunk
+streams by (required message count = d_in + 1), and for GCN via
+self-loops.
 """
 
 from __future__ import annotations
@@ -31,7 +35,7 @@ from repro.graphs.csr import CSRGraph, degrees_from_csr
 
 @dataclasses.dataclass(frozen=True)
 class GNNLayerSpec:
-    kind: str  # 'gcn' | 'sage' | 'gin'
+    kind: str  # 'gcn' | 'sage' | 'sagepool' | 'gin'
     in_dim: int
     out_dim: int
     activation: bool  # ReLU after update (False on final layer)
@@ -44,11 +48,37 @@ class GNNLayerSpec:
         SAGE doubles the width (self ; neighbor-agg) — the paper calls out
         the resulting eviction pressure explicitly (§4.3).
         """
-        return 2 * self.in_dim if self.kind == "sage" else self.in_dim
+        return 2 * self.in_dim if self.concat_self else self.in_dim
+
+    @property
+    def concat_self(self) -> bool:
+        """Hot layout ``[self ; neighbours]``: the self message fills
+        columns ``[0, d)`` and the neighbour aggregate ``[d, 2d)``."""
+        return self.kind in ("sage", "sagepool")
+
+    @property
+    def neighbour_offset(self) -> int:
+        """First hot-store column of the neighbour aggregate."""
+        return self.in_dim if self.concat_self else 0
+
+    @property
+    def aggregation(self) -> str:
+        """How a destination combines its neighbours' messages: ``"sum"``
+        of weighted rows, or the pooling aggregator's elementwise
+        ``"max"`` of projected rows (every one ``>= 0``)."""
+        return "max" if self.kind == "sagepool" else "sum"
+
+    @property
+    def source_projection(self):
+        """``(w_pool, b_pool)`` of the pooling aggregator's message
+        ``ReLU(h_u @ w_pool + b_pool)``; None for the other kinds."""
+        if self.kind != "sagepool":
+            return None
+        return self.params["w_pool"], self.params["b_pool"]
 
     @property
     def extra_self_message(self) -> bool:
-        return self.kind in ("sage", "gin")
+        return self.kind in ("sage", "sagepool", "gin")
 
 
 def init_gnn_params(
@@ -66,6 +96,13 @@ def init_gnn_params(
         elif kind == "sage":
             w = _glorot(rng, (2 * d_in, d_out))
             params = {"w": w, "b": np.zeros(d_out, np.float32)}
+        elif kind == "sagepool":
+            params = {
+                "w_pool": _glorot(rng, (d_in, d_in)),
+                "b_pool": np.zeros(d_in, np.float32),
+                "w": _glorot(rng, (2 * d_in, d_out)),
+                "b": np.zeros(d_out, np.float32),
+            }
         elif kind == "gin":
             h = max(d_in, d_out)
             params = {
@@ -101,8 +138,9 @@ def _glorot(rng, shape) -> np.ndarray:
 
 def edge_weights(
     kind: str, src: np.ndarray, dst: np.ndarray, in_deg: np.ndarray
-) -> np.ndarray:
-    """Per-edge scalar applied to the source embedding."""
+) -> np.ndarray | None:
+    """Per-edge scalar applied to the source embedding; None for the
+    pooling aggregator, whose max takes no weights."""
     if kind == "gcn":
         d = np.maximum(in_deg, 1).astype(np.float64)
         return (1.0 / np.sqrt(d[src] * d[dst])).astype(np.float32)
@@ -111,6 +149,8 @@ def edge_weights(
         return (1.0 / d[dst]).astype(np.float32)
     if kind == "gin":
         return np.ones(len(src), dtype=np.float32)
+    if kind == "sagepool":
+        return None
     raise ValueError(kind)
 
 
@@ -118,7 +158,34 @@ def self_coefficient(spec: GNNLayerSpec) -> float:
     """Scale applied to a vertex's own embedding in its self message."""
     if spec.kind == "gin":
         return 1.0 + float(spec.params["eps"])
-    return 1.0  # sage: raw copy into the self half
+    return 1.0  # sage, sagepool: raw copy into the self half
+
+
+def pool_projection(projection, h: np.ndarray) -> np.ndarray:
+    """The pooling aggregator's messages ``ReLU(h @ w_pool + b_pool)``,
+    ``projection`` being ``(w_pool, b_pool)``."""
+    w_pool, b_pool = projection
+    return np.maximum(h @ w_pool + b_pool, 0.0).astype(np.float32)
+
+
+def aggregate_neighbours(
+    spec: GNNLayerSpec,
+    src_rows: np.ndarray,  # [m, d_in] the source row of each edge
+    dst: np.ndarray,  # [m] the edge's destination, in [0, num_dst)
+    num_dst: int,
+    w: np.ndarray | None,  # [m] edge weights (sum aggregation)
+) -> np.ndarray:
+    """``[num_dst, d_in]``: per destination the weighted sum of its edges'
+    rows, or for the pooling aggregator the elementwise max of their
+    projections.  Every projection is ``>= 0`` after the ReLU, so the zero
+    fill is the max's identity, and a destination with no edge reads 0."""
+    agg = np.zeros((num_dst, spec.in_dim), dtype=np.float32)
+    if spec.aggregation == "max":
+        rows = pool_projection(spec.source_projection, src_rows)
+        np.maximum.at(agg, dst, rows)
+    else:
+        np.add.at(agg, dst, src_rows * w[:, None])
+    return agg
 
 
 # --------------------------------------------------------------------------
@@ -128,7 +195,7 @@ def self_coefficient(spec: GNNLayerSpec) -> float:
 
 def layer_update(spec: GNNLayerSpec, agg: np.ndarray) -> np.ndarray:
     """Dense transform on finalized aggregate rows [n, hot_width]."""
-    if spec.kind in ("gcn", "sage"):
+    if spec.kind in ("gcn", "sage", "sagepool"):
         out = agg @ spec.params["w"] + spec.params["b"]
     elif spec.kind == "gin":
         h = agg @ spec.params["w1"] + spec.params["b1"]
@@ -159,10 +226,8 @@ def dense_reference(
     h = features.astype(np.float32)
     for spec in specs:
         w = edge_weights(spec.kind, src, dst, in_deg)
-        msgs = h[src] * w[:, None]
-        agg = np.zeros((csr.num_vertices, spec.in_dim), dtype=np.float32)
-        np.add.at(agg, dst, msgs)
-        if spec.kind == "sage":
+        agg = aggregate_neighbours(spec, h[src], dst, csr.num_vertices, w)
+        if spec.concat_self:
             agg = np.concatenate([h * self_coefficient(spec), agg], axis=1)
         elif spec.kind == "gin":
             agg = agg + h * self_coefficient(spec)

@@ -88,6 +88,23 @@ def test_shard_sweep_bit_identity(tmp_path, kind):
             assert version.epoch in store.servable_versions(result.final.layer)
 
 
+def test_dist_session_refuses_sagepool_by_name(tmp_path):
+    """The shards add remote partials, so a max aggregation is refused,
+    naming the kind, before any layer work — not returned as a sum."""
+    from repro.graphs.synth import make_features, powerlaw_graph
+    from repro.models.gnn import init_gnn_params
+
+    csr = powerlaw_graph(200, 4, seed=3)
+    store = GraphStore.create(str(tmp_path / "store"), csr,
+                              make_features(200, 8, seed=3), num_partitions=2)
+    specs = init_gnn_params("sagepool", [8, 4], seed=1)
+    with DistSession(store, shards=2, config=dist_cfg(),
+                     workdir=str(tmp_path / "dist"), workers="thread") as dist:
+        with pytest.raises(ValueError, match="layer kind 'sagepool'"):
+            dist.infer(specs)
+    assert not os.path.exists(tmp_path / "dist" / "layer_1")
+
+
 def test_multi_shard_runs_exchange_real_traffic(tmp_path):
     """The ring-offset exact graph has cross-boundary edges, so 2-shard
     runs must route real bytes through the exchange — guards against a

@@ -9,8 +9,9 @@ import numpy as np
 import pytest
 
 from repro.core.atlas import AtlasConfig, AtlasEngine, spills_to_dense
+from repro.core.gather_ref import layerwise_gather, vertexwise_gather
 from repro.core.reorder import make_order, relabel_features_chunked, relabel_graph, relabel_map
-from repro.graphs.csr import degrees_from_csr
+from repro.graphs.csr import build_csr, degrees_from_csr
 from repro.graphs.synth import make_features, powerlaw_graph
 from repro.models.gnn import dense_reference, init_gnn_params
 from repro.storage.layout import GraphStore
@@ -28,7 +29,7 @@ def run_engine(tmp_path, csr, feats, specs, cfg):
     return out, metrics
 
 
-@pytest.mark.parametrize("kind", ["gcn", "sage", "gin"])
+@pytest.mark.parametrize("kind", ["gcn", "sage", "sagepool", "gin"])
 def test_broadcast_matches_dense(tmp_path, kind):
     csr = powerlaw_graph(V, 6, seed=11)
     feats = make_features(V, D_IN, seed=11)
@@ -72,6 +73,93 @@ def test_sage_under_eviction_concat_state(tmp_path):
     out, m = run_engine(tmp_path, csr, feats, specs, cfg)
     assert m[0].evictions > 0
     assert np.abs(out - ref).max() < 1e-4
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax", "pallas-interpret"])
+def test_sagepool_under_eviction_max_state(tmp_path, backend):
+    """The pooling aggregator's max partials survive evict -> reload cycles
+    mid-layer, in both layers.  The features are negative about half the
+    time, so a max over the self half (zero-filled: max(0, h) != h) or a
+    ``+=`` of the neighbour half's per-chunk maxima would fail this."""
+    csr = powerlaw_graph(800, 5, seed=17)
+    feats = make_features(800, 12, seed=17)
+    assert (feats < 0).mean() > 0.4
+    specs = init_gnn_params("sagepool", [12, 10, 8], seed=3)
+    ref = dense_reference(csr, feats, specs)
+    cfg = AtlasConfig(chunk_bytes=40 * 12 * 4, hot_slots=100, eviction="at",
+                      backend=backend)
+    out, metrics = run_engine(tmp_path, csr, feats, specs, cfg)
+    for m in metrics:
+        assert m.evictions > 0 and m.reloads > 0
+        assert m.max_merge_seconds > 0.0
+    assert np.abs(out - ref).max() < 1e-4
+
+
+@pytest.mark.parametrize("gather", [layerwise_gather, vertexwise_gather])
+def test_gather_baselines_compute_sagepool(gather):
+    csr = powerlaw_graph(300, 5, seed=21)
+    feats = make_features(300, 8, seed=21)
+    specs = init_gnn_params("sagepool", [8, 6, 4], seed=6)
+    out, _ = gather(csr, feats, specs, batch_size=64)
+    np.testing.assert_allclose(out, dense_reference(csr, feats, specs),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_sagepool_reference_takes_the_max_of_projected_rows():
+    """``dense_reference`` against a per-vertex loop: the max of each
+    in-neighbour's ``ReLU(h W_pool + b_pool)``, 0 with no in-edge, beside
+    the vertex's own row."""
+    rng = np.random.default_rng(2)
+    # destinations below 100 only: vertices 100-119 have no in-edge
+    csr = build_csr(rng.integers(0, 120, 400), rng.integers(0, 100, 400),
+                    120)
+    feats = make_features(120, 6, seed=2)
+    (spec,) = init_gnn_params("sagepool", [6, 5], seed=4)
+    spec.params["b_pool"][:] = np.linspace(-0.5, 0.5, 6)
+    src, dst = csr.edges_for_range(0, 120)
+    p = feats @ spec.params["w_pool"] + spec.params["b_pool"]
+    agg = np.zeros((120, 6), np.float32)
+    empty = 0
+    for v in range(120):
+        nbrs = src[dst == v]
+        if len(nbrs):
+            agg[v] = np.maximum(p[nbrs], 0).max(axis=0)
+        else:
+            empty += 1
+    assert empty > 0
+    want = np.concatenate([feats, agg], axis=1) @ spec.params["w"] \
+        + spec.params["b"]
+    np.testing.assert_allclose(dense_reference(csr, feats, [spec]), want,
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_hot_store_max_combine_starts_from_the_zero_fill(tmp_path):
+    """The neighbour columns take the max of what arrives, starting from
+    a fresh slot's zero fill (the identity for messages >= 0); the self
+    columns still add."""
+    from repro.core.eviction import make_policy
+    from repro.core.memory_manager import MemoryManager
+    from repro.core.orchestrator import Orchestrator
+    from repro.storage.coldstore import ColdStore
+
+    orch = Orchestrator(np.full(4, 3, dtype=np.int64))
+    cold = ColdStore(str(tmp_path / "cold.bin"), dim=6, dtype=np.float32)
+    mm = MemoryManager(4, 6, np.float32, orch,
+                       make_policy("at", num_vertices=4, max_pending=3), cold)
+    v = np.array([0, 2])
+    slots = mm.activate(v)
+    mm.accumulate(v, np.full((2, 3), -1.0, np.float32), 0, slots)
+    mm.accumulate(v, np.array([[0.5, 0, 2], [1, 1, 1]], np.float32), 3,
+                  slots, combine="max")
+    mm.accumulate(v, np.array([[1, 0, 1], [0, 3, 0]], np.float32), 3,
+                  slots, combine="max")
+    np.testing.assert_array_equal(mm.hot[slots], [[-1, -1, -1, 1, 0, 2],
+                                                  [-1, -1, -1, 1, 3, 1]])
+    assert mm.max_merge_seconds > 0.0
+    with pytest.raises(ValueError, match="unknown combine"):
+        mm.accumulate(v, np.zeros((2, 3), np.float32), 3, slots,
+                      combine="min")
+    cold.close()
 
 
 def test_jax_backend_matches(tmp_path):

@@ -74,19 +74,26 @@ def _chunk(rng, n, d, m, num_dst):
         (200, 24, 3000, 700),
     ],
 )
-@pytest.mark.parametrize("kind", ["gcn", "sage", "gin"])
+@pytest.mark.parametrize("kind", ["gcn", "sage", "sagepool", "gin"])
 def test_backend_equivalence_grid(backend, n, d, m, num_dst, kind):
     """Every backend returns the numpy oracle's (u_dst, counts) exactly
-    and its partial sums to fp32 tolerance, for every weighting."""
+    and its partials to fp32 tolerance, for every weighting and for the
+    pooling aggregator's projected max."""
     rng = np.random.default_rng(n * 7 + m + d)
     feats, src_local, dst = _chunk(rng, n, d, m, num_dst)
     # realistic per-kind edge weights (gcn: symmetric norm, sage: 1/deg,
-    # gin: ones) computed from a synthetic degree vector
+    # gin: ones; none for the max) computed from a synthetic degree vector
     in_deg = rng.integers(1, 9, num_dst).astype(np.int64)
     src_g = rng.integers(0, num_dst, m).astype(np.int64)  # fake global ids
-    w = edge_weights(kind, src_g, dst, in_deg).astype(np.float32)
-    ref_u, ref_p, ref_c = chunk_aggregate_numpy(feats, src_local, dst, w)
-    agg = chunk_aggregate(backend)
+    w = edge_weights(kind, src_g, dst, in_deg)
+    (spec,) = init_gnn_params(kind, [d, 4], seed=n + m)
+    if kind == "sagepool":
+        # a bias that leaves some projected columns 0 and others not
+        spec.params["b_pool"][:] = rng.normal(0, 0.3, d)
+    projection = spec.source_projection
+    ref_u, ref_p, ref_c = chunk_aggregate_numpy(feats, src_local, dst, w,
+                                                projection)
+    agg = chunk_aggregate(backend, projection=projection)
     u, p, c = agg(feats, src_local, dst, w)
     assert u.dtype == np.int64 and c.dtype == np.int64
     np.testing.assert_array_equal(u, ref_u)
@@ -127,6 +134,32 @@ def test_chunk_aggregate_dispatcher():
         chunk_aggregate("pallas")
     with pytest.raises(ValueError, match="unknown broadcast backend"):
         chunk_aggregate("cuda")
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax", "pallas-interpret"])
+def test_chunk_aggregate_takes_the_max_given_a_projection(backend):
+    """A layer's projection alone makes an aggregator the pooling max:
+    per destination the elementwise max of ``ReLU(h W_pool + b_pool)``
+    over its edges, edge weights ignored; without one, the weighted sum."""
+    rng = np.random.default_rng(11)
+    (spec,) = init_gnn_params("sagepool", [6, 2], seed=1)
+    spec.params["b_pool"][:] = rng.normal(0, 0.3, 6)
+    feats = rng.normal(size=(9, 6)).astype(np.float32)
+    src_local = rng.integers(0, 9, 40).astype(np.int64)
+    dst = rng.integers(0, 7, 40).astype(np.int64)
+    w = rng.uniform(-1, 1, 40).astype(np.float32)
+    w_pool, b_pool = spec.source_projection
+    rows = np.maximum(feats @ w_pool + b_pool, 0.0)
+    u, p, _ = chunk_aggregate(backend, projection=spec.source_projection)(
+        feats, src_local, dst, w)
+    for k, v in enumerate(u):
+        np.testing.assert_allclose(p[k], rows[src_local[dst == v]].max(0),
+                                   rtol=1e-5, atol=1e-6)
+    u, p, _ = chunk_aggregate(backend)(feats, src_local, dst, w)
+    for k, v in enumerate(u):
+        e = dst == v
+        np.testing.assert_allclose(p[k], (feats[src_local[e]] * w[e, None])
+                                   .sum(0), rtol=1e-4, atol=1e-5)
 
 
 def test_pallas_backend_session_fails_without_tpu(tmp_path):

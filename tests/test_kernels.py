@@ -13,6 +13,7 @@ import jax.numpy as jnp
 pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
+from repro.kernels.edge_block_segmax import edge_block_segmax
 from repro.kernels.edge_block_spmm import edge_block_spmm
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_graduate import fused_graduate
@@ -88,6 +89,65 @@ def test_spmm_bf16_inputs():
                           block_e=128, block_v=128, block_dst=128, block_d=64)
     want = ref.edge_block_spmm_ref(feats.astype(jnp.float32), src, dst, w, 128)
     np.testing.assert_allclose(out, want, rtol=2e-2, atol=2e-2)
+
+
+# ------------------------------------------------------------- segmax
+
+
+def _segment_max_numpy(rows, src, dst, num_dst):
+    """The elementwise max of each destination's source rows, 0 for a
+    destination with no edge."""
+    out = np.full((num_dst, rows.shape[1]), -np.inf, np.float32)
+    np.maximum.at(out, dst, rows[src])
+    out[np.isneginf(out)] = 0.0
+    return out
+
+
+@pytest.mark.parametrize(
+    "v_src,num_dst,e,d,blocks",
+    [(64, 64, 200, 16, (128, 256, 128, 64)),
+     # 2 d tiles, 3 src tiles, dst tile boundaries inside edge blocks
+     (600, 260, 3000, 72, (128, 256, 128, 64)),
+     # two hub destinations, each over several edge blocks
+     (64, 2, 1000, 16, (128, 256, 128, 64)),
+     # most destinations empty, whole tiles of them
+     (50, 2000, 512, 64, (64, 64, 64, 64)),
+     # a single edge, blocks from auto_blocks
+     (40, 1, 1, 8, None),
+     # sentinel padding: 300 edges in 3 blocks of 128
+     (90, 40, 300, 24, (128, 128, 64, 8))],
+)
+def test_segmax_shapes(v_src, num_dst, e, d, blocks):
+    """The kernel (interpret mode) gives the numpy segment max bit for bit:
+    negative and positive rows, sentinel-padded edge blocks, destinations
+    with no edge (0) and hubs over several blocks."""
+    rng = np.random.default_rng(e + d)
+    rows = rng.normal(size=(v_src, d)).astype(np.float32)
+    src = rng.integers(0, v_src, e).astype(np.int32)
+    dst = rng.integers(0, num_dst, e).astype(np.int32)
+    be, bv, bdst, bd = blocks or (None,) * 4
+    out = edge_block_segmax(
+        jnp.asarray(rows), src, dst, num_dst, block_e=be, block_v=bv,
+        block_dst=bdst, block_d=bd, interpret=True,
+    )
+    want = _segment_max_numpy(rows, src, dst, num_dst)
+    np.testing.assert_array_equal(np.asarray(out), want)
+
+
+def test_segmax_empty_and_all_negative():
+    """No edge gives zeros without a launch; a destination whose rows are
+    all negative keeps its (negative) max, not 0."""
+    rows = -np.abs(np.random.default_rng(1).normal(size=(16, 8))).astype(
+        np.float32) - 1.0
+    empty = edge_block_segmax(jnp.asarray(rows), np.zeros(0, np.int32),
+                              np.zeros(0, np.int32), 5, interpret=True)
+    assert np.array_equal(np.asarray(empty), np.zeros((5, 8), np.float32))
+    src = np.arange(16, dtype=np.int32)
+    dst = (np.arange(16) % 3).astype(np.int32)
+    out = np.asarray(edge_block_segmax(jnp.asarray(rows), src, dst, 4,
+                                       interpret=True))
+    np.testing.assert_array_equal(out, _segment_max_numpy(rows, src, dst, 4))
+    assert (out[:3] < 0).all() and (out[3] == 0).all()
 
 
 # ----------------------------------------------------------- graduate

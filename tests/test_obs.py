@@ -392,10 +392,10 @@ ROUND_TRIP = ("dedup_seconds", "h2d_seconds", "kernel_wait_seconds",
               "d2h_seconds", "deliver_seconds", "evict_seconds")
 
 
-def _run_pallas(root, trace, v=400):
-    """Two SAGE layers through the Pallas kernel (interpret mode) and the
-    staged pipeline, with a hot store a quarter of the vertices, so that
-    eviction and reload run in both layers."""
+def _run_pallas(root, trace, v=400, kind="sage"):
+    """Two SAGE layers (or ``kind``'s) through the Pallas kernel
+    (interpret mode) and the staged pipeline, with a hot store a quarter
+    of the vertices, so that eviction and reload run in both layers."""
     d = 16
     csr = powerlaw_graph(v, 5, seed=43)
     feats = make_features(v, d, seed=43)
@@ -404,7 +404,7 @@ def _run_pallas(root, trace, v=400):
                       backend="pallas-interpret", pipeline="staged")
     session = AtlasSession(store, cfg, workdir=str(root / "run"),
                            trace=trace)
-    result = session.infer(init_gnn_params("sage", [d, 8, 8], seed=9))
+    result = session.infer(init_gnn_params(kind, [d, 8, 8], seed=9))
     session.close()
     return result
 
@@ -424,7 +424,9 @@ def test_round_trip_counters_reconcile_with_spans(tmp_path):
     events = load_trace(res.trace_path)
     assert validate_trace(events) == []
     report = analyze(events)
-    for cats in RECONCILE.values():
+    for field, cats in RECONCILE.items():
+        if field == "max_merge_seconds":
+            continue  # the max's: test_max_merge_reconciles_with_its_spans
         for cat in cats:
             assert report["category_seconds"].get(cat, 0.0) > 0.0, cat
     # the fields this test is about; the others carry run-level spans (the
@@ -454,6 +456,45 @@ def test_obs_report_prints_spmm_step_ratio(tmp_path, capsys):
         assert r["ratio"] == pytest.approx(m.spmm_steps / m.spmm_dense_steps)
         assert (f"layer {m.layer}: {m.spmm_steps:,} of "
                 f"{m.spmm_dense_steps:,} ({r['ratio']:.2%})") in printed
+
+
+def test_max_merge_reconciles_with_its_spans(tmp_path, capsys):
+    """The pooling aggregator's max combine is timed into
+    ``max_merge_seconds`` and traced as ``max_merge`` (category
+    ``merge``) inside ``deliver``; the two agree within obs_report's 5%,
+    and obs_report prints the segmax kernel's grid steps."""
+    from repro.launch.obs_report import main
+
+    res = _run_pallas(tmp_path, trace=True, kind="sagepool")
+    for m in res.metrics:
+        assert m.evictions > 0 and m.reloads > 0
+        assert 0.0 < m.max_merge_seconds < m.deliver_seconds
+        assert 0 < m.segmax_steps <= m.segmax_dense_steps
+        assert m.spmm_steps == m.spmm_dense_steps == 0
+    events = load_trace(res.trace_path)
+    report = analyze(events)
+    assert report["category_seconds"]["merge"] > 0.0
+    # every max_merge span lies inside a deliver span
+    merge_regions = [set(k.split("+")) for k in report["region_seconds"]
+                     if "merge" in k.split("+")]
+    assert merge_regions and all("deliver" in r for r in merge_regions)
+    problems = reconcile(report, [m.as_dict() for m in res.metrics])
+    assert [p for p in problems
+            if p.split(":")[0] in ("max_merge_seconds",
+                                   "deliver_seconds")] == []
+
+    telemetry = tmp_path / "telemetry.json"
+    telemetry.write_text(json.dumps(res.telemetry))
+    out_json = tmp_path / "report.json"
+    assert main([res.trace_path, "--telemetry", str(telemetry),
+                 "--json", str(out_json)]) == 0
+    printed = capsys.readouterr().out
+    rows = json.loads(out_json.read_text())["segmax_steps"]
+    assert "spmm_steps" not in json.loads(out_json.read_text())
+    assert "edge_block_segmax grid steps" in printed
+    for r, m in zip(rows, res.metrics):
+        assert (r["steps"], r["dense_steps"]) == (
+            m.segmax_steps, m.segmax_dense_steps)
 
 
 def test_tracing_changes_no_count_and_no_output(tmp_path):
